@@ -13,13 +13,24 @@ import subprocess
 import jax
 
 
-def provenance(fast: bool | None = None) -> dict:
+def device_info() -> dict:
+    """The devices this process's jax computations ran on."""
+    devices = jax.devices()
+    return {
+        "backend": devices[0].platform,
+        "device_kind": getattr(devices[0], "device_kind", str(devices[0])),
+        "device_count": len(devices),
+    }
+
+
+def provenance(fast: bool | None = None, device: dict | None = None) -> dict:
     """Where/how this artifact was produced — stamped into every BENCH_*.json.
 
     Cross-machine regression-gate trips are undiagnosable without knowing
     both sides' git commit, jax version, backend/device and fast-vs-full
     mode; check_regression.py prints this block from both artifacts in its
-    failure messages."""
+    failure messages.  ``device`` is the :func:`device_info` of the process
+    that did the work, when that was not this one (worker processes)."""
     try:
         commit = subprocess.run(
             ["git", "rev-parse", "--short", "HEAD"],
@@ -28,12 +39,10 @@ def provenance(fast: bool | None = None) -> dict:
         ).stdout.strip() or "unknown"
     except (OSError, subprocess.SubprocessError):
         commit = "unknown"
-    dev = jax.devices()[0]
     prov = {
         "git_commit": commit,
         "jax_version": jax.__version__,
-        "backend": jax.default_backend(),
-        "device_kind": getattr(dev, "device_kind", str(dev)),
+        **(device if device is not None else device_info()),
     }
     if fast is not None:
         prov["mode"] = "fast" if fast else "full"
@@ -71,11 +80,16 @@ def bench_main(run) -> None:
     """CLI entry shared by the bench modules (``run(fast: bool) -> rows``).
 
     Fast mode pins JAX_PLATFORMS=cpu before the first jax computation unless
-    the caller already chose a platform — the same contract as run.py."""
+    the caller already chose a platform — the same contract as run.py; the
+    artifact's provenance then names the CPU, the device that did the
+    work."""
     parser = argparse.ArgumentParser()
     parser.add_argument("--full", action="store_true")
     args = parser.parse_args()
     if not args.full:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from repro.runtime import enable_compile_cache
+
+    enable_compile_cache()
     for row in run(fast=not args.full):
         print(row)

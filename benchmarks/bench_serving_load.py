@@ -19,7 +19,14 @@ per-request query latency at N ∈ {1e5, 1e6}:
 Every mode runs in its OWN subprocess: XLA_FLAGS must be set before jax
 initialises, and a fresh process also gives each engine a cold, honest
 compile cache.  Workers run sequentially (the CI runner has 2 cores —
-parallel workers would measure contention).  Per mode the drive runs
+parallel workers would measure contention), and the parent never touches
+jax: a chip belongs to one process at a time, so the parent must leave it
+free for each worker.  Fast mode pins the workers to the CPU (unless the
+caller chose a platform); ``--full`` workers inherit the caller's platform.
+Each worker reports the devices it ran on, and the artifact's provenance is
+theirs, not the parent's.  On a one-chip host the ``sharded*`` modes fail
+(they need 2 and 4 devices; the forced host device count only applies to
+the CPU).  Per mode the drive runs
 warmup + 2 timed reps from an identical rebuilt state; the artifact keeps
 best-of-reps (max QPS, min percentiles) — the min-of-reps discipline of
 `_util.timeit_result(best=True)` lifted to a closed-loop drive.
@@ -184,6 +191,9 @@ def _worker(args) -> None:
     from repro import serving
     from repro.core import modulation, walks
     from repro.graphs import generators
+    from repro.runtime import enable_compile_cache
+
+    enable_compile_cache()
 
     fast = not args.full
     cfg = (
@@ -230,14 +240,18 @@ def _worker(args) -> None:
             best["qps"] = max(best["qps"], metrics["qps"])
             for k in ("p50_ms", "p99_ms", "wall_s"):
                 best[k] = min(best[k], metrics[k])
-    best.update(mode=args.mode, nodes=args.nodes, shards=args.shards)
+    from benchmarks._util import device_info
+
+    best.update(mode=args.mode, nodes=args.nodes, shards=args.shards,
+                device=device_info())
     print("RESULT " + json.dumps(best), flush=True)
 
 
 def _spawn(mode: str, shards: int, n: int, ticks: int, fast: bool):
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    if fast:
+        env.setdefault("JAX_PLATFORMS", "cpu")
     extra = f"{os.path.join(root, 'src')}:{root}"
     env["PYTHONPATH"] = (
         f"{extra}:{env['PYTHONPATH']}" if env.get("PYTHONPATH") else extra
@@ -305,11 +319,19 @@ def run(fast: bool = True):
                 )
 
     from benchmarks._util import provenance
-    import jax
 
+    # Modes differ in device count (that is their point); the platform and
+    # chip kind must agree, or the rows are not comparable.
+    kinds = {(r["device"]["backend"], r["device"]["device_kind"])
+             for per in per_size.values() for r in per.values()}
+    if len(kinds) != 1:
+        raise RuntimeError(f"serving_load workers ran on {sorted(kinds)}; "
+                           "need exactly one platform and device kind")
+    backend, kind = kinds.pop()
+    device = {"backend": backend, "device_kind": kind}
     artifact = {
-        "provenance": provenance(fast),
-        "host_backend": jax.default_backend(),
+        "provenance": provenance(fast, device=device),
+        "host_backend": device["backend"],
         "unit": "ms",
         "capacity": CAPACITY,
         "batch": BATCH,
@@ -342,8 +364,6 @@ def main() -> None:
     if args.worker:
         _worker(args)
         return
-    if not args.full:
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
     for row in run(fast=not args.full):
         print(row)
 

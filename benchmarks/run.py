@@ -13,7 +13,14 @@ Flags:
 
 Fast mode (no --full) pins JAX_PLATFORMS=cpu before jax initialises unless
 the environment already chose a platform — the same contract as the
-``python -m benchmarks.bench_*`` entry points, so CI and local runs agree.
+``python -m benchmarks.bench_*`` entry points, so CI and local runs agree;
+every artifact's provenance names the device that did the work.
+
+``serving_load`` starts one worker process per mode.  A chip belongs to one
+process at a time, and the in-process suites hold it once they have run, so
+off the CPU ``serving_load`` runs only in an invocation of its own
+(``--only=serving_load``); combined with other suites it is skipped with a
+note.
 """
 from __future__ import annotations
 
@@ -43,6 +50,9 @@ def main() -> None:
 
     if fast:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from repro.runtime import enable_compile_cache
+
+    enable_compile_cache()
 
     if backend is not None:
         from repro.kernels import dispatch
@@ -83,11 +93,22 @@ def main() -> None:
         ("classification (Table 7)", bench_classification),
         ("roofline (§Roofline)", roofline),
     ]
+    import jax
+
     if only is not None:
         # Exact first-token match wins over prefix: --only=serving must run
         # the serving suite alone, not also serving_load.
         exact = [s for s in suites if s[0].split(" ", 1)[0] == only]
         suites = exact if exact else [s for s in suites if s[0].startswith(only)]
+    # Asking JAX for the platform takes the chip, which is harmless only
+    # when this process runs in-process suites anyway.
+    if len(suites) > 1 and jax.default_backend() != "cpu":
+        kept = [s for s in suites if s[1] is not bench_serving_load]
+        if len(kept) < len(suites):
+            print("# serving_load skipped: its workers need the chip, which "
+                  "this process holds once another suite has run; run "
+                  "--only=serving_load on its own", flush=True)
+        suites = kept
     for label, mod in suites:
         t0 = time.time()
         try:

@@ -27,6 +27,7 @@ from repro.bo import baselines, thompson
 from repro.checkpoint import CheckpointManager
 from repro.core import modulation, walks
 from repro.graphs import generators
+from repro.runtime import enable_compile_cache
 
 
 def main():
@@ -43,6 +44,7 @@ def main():
     ap.add_argument("--record", metavar="PATH", default=None,
                     help="stream a JSONL flight record of the run")
     args = ap.parse_args()
+    enable_compile_cache()
 
     recording = (
         obs.recording(args.record) if args.record is not None

@@ -24,6 +24,7 @@ from repro.core import features, kernels_exact, modulation, walks
 from repro.graphs import generators, signals
 from repro.gp import exact, mll, posterior
 from repro.kernels.walk_sampler.rng import SCHEMES
+from repro.runtime import enable_compile_cache
 
 
 def main():
@@ -33,6 +34,7 @@ def main():
     parser.add_argument("--skip-exact", action="store_true",
                         help="skip the O(N^3) exact-GP baseline")
     args = parser.parse_args()
+    enable_compile_cache()
     # --- problem: noisy observations of a smooth signal on a 20×20 grid ----
     g = generators.grid2d(20, 20)
     n = g.n_nodes

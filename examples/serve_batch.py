@@ -10,6 +10,7 @@ import numpy as np
 from repro.configs import get_config, reduce_config
 from repro.launch.serve import Request, ServeLoop
 from repro.models import model
+from repro.runtime import enable_compile_cache
 
 
 def main():
@@ -18,6 +19,7 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--new-tokens", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = reduce_config(get_config(args.arch))
     params = model.init_params(cfg, jax.random.PRNGKey(0))

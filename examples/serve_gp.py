@@ -19,14 +19,21 @@ LML-ascent steps on the streamed observations first (a noise/lengthscale
 calibration pass) — that is what puts per-solve CG diagnostics into the
 record, since the serving hot path itself is CG-free by design.
 
-``--mesh N`` re-serves the state over an N-way host device mesh
-(DESIGN.md §3.12): the cached train rows are row-sharded, queries run
-under shard_map, and the script asserts bitwise parity against the
-single-device answers — the CI distributed-serving smoke.  The flag forces
-``--xla_force_host_platform_device_count=N`` before jax initialises, so it
-works on a plain CPU runner:
+``--mesh N`` re-serves the state over an N-device mesh (DESIGN.md §3.12):
+the cached train rows are row-sharded, queries run under shard_map, and the
+script asserts bitwise parity against the single-device answers — the CI
+distributed-serving smoke.  On a TPU host the mesh is the chips; without an
+accelerator the flag makes XLA expose N host devices (it sets
+``--xla_force_host_platform_device_count=N`` before jax initialises, which
+only affects the CPU platform), so it also works on a plain CPU runner:
 
     PYTHONPATH=src python examples/serve_gp.py --nodes 20000 --mesh 2
+
+Memory: the default Barabási–Albert graph pads every adjacency row to the
+maximum degree (graphs/formats.py).  At 10⁶ nodes that is ≈ 2,575 slots,
+≈ 20.6 GB of neighbours and weights — more than one TPU v5e chip's 16 GB of
+HBM — so on one chip pass ``--nodes`` ≤ ~10⁵, or use the ring graph of
+``chip_smoke.py``, until the offsets-based adjacency (ROADMAP R2) lands.
 """
 import argparse
 import contextlib
@@ -34,8 +41,10 @@ import os
 import sys
 import time
 
-# --mesh needs the forced host device count in XLA_FLAGS before the
-# backend initialises — i.e. before jax is imported.
+# --mesh on a host without an accelerator needs the forced host device
+# count in XLA_FLAGS before the backend initialises — i.e. before jax is
+# imported.  The flag only affects the CPU platform, so a TPU host still
+# shards over its chips.
 _mesh_arg = next(
     (i for i, a in enumerate(sys.argv) if a.startswith("--mesh")), None
 )
@@ -48,7 +57,6 @@ if _mesh_arg is not None:
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={_n}"
         ).strip()
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
 import numpy as np
@@ -57,6 +65,7 @@ from repro import obs, serving
 from repro.core import modulation, walks
 from repro.graphs import generators
 from repro.resilience import faults
+from repro.runtime import enable_compile_cache
 
 
 def main():
@@ -76,6 +85,7 @@ def main():
                     help="LML-ascent steps on the observations before "
                          "serving (exercises the CG solve path)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     recording = (
         obs.recording(args.record) if args.record is not None
@@ -209,7 +219,8 @@ def run(args):
     if args.mesh > 1:
         # Distributed serving smoke: same state, row-sharded over the host
         # mesh, must answer bit-identically (structural-zero psum).
-        print(f"re-serving over a {args.mesh}-way host mesh ...")
+        print(f"re-serving over a {args.mesh}-way "
+              f"{jax.default_backend()} mesh ...")
         sharded = serving.ShardedServeState(state, n_shards=args.mesh)
         qsub = qnodes[:64].astype(np.int32)
         ms, vs = sharded.posterior_moments(qsub)

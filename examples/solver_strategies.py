@@ -23,6 +23,7 @@ from repro import solvers
 from repro.core import linops, modulation, walks
 from repro.gp import mll
 from repro.graphs import generators
+from repro.runtime import enable_compile_cache
 
 
 def main() -> int:
@@ -31,6 +32,7 @@ def main() -> int:
     ap.add_argument("--train", type=int, default=256)
     ap.add_argument("--rank", type=int, default=64)
     args = ap.parse_args()
+    enable_compile_cache()
 
     g = generators.ring(args.nodes, k=3)
     cfg = walks.WalkConfig(n_walkers=8, p_halt=0.15, l_max=5)

@@ -11,6 +11,7 @@ import argparse
 from repro.configs import get_config, reduce_config
 from repro.launch.train import train_loop
 from repro.models.config import LayerSpec, ModelConfig
+from repro.runtime import enable_compile_cache
 
 
 def preset_100m() -> ModelConfig:
@@ -33,6 +34,7 @@ def main():
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--microbatches", type=int, default=1)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.preset == "100m":
         cfg = preset_100m()

@@ -12,6 +12,7 @@ import numpy as np
 from repro.core import features, modulation, walks
 from repro.gp import mll, posterior
 from repro.graphs import generators, signals
+from repro.runtime import enable_compile_cache
 
 
 def main():
@@ -19,6 +20,7 @@ def main():
     ap.add_argument("--nodes", type=int, default=2000)
     ap.add_argument("--walkers", type=int, default=100)
     args = ap.parse_args()
+    enable_compile_cache()
 
     g, xyz = generators.knn_sphere(args.nodes, k=6, seed=0)
     wind = signals.wind_field_sphere(xyz, seed=0)

@@ -103,7 +103,8 @@ def khat_diag_exact(trace: WalkTrace, f: jax.Array) -> jax.Array:
     """
     vals = feature_values(trace, f)
     same = trace.cols[:, :, None] == trace.cols[:, None, :]
-    return jnp.einsum("mk,ml,mkl->m", vals, vals, same.astype(vals.dtype))
+    outer = vals[:, :, None] * vals[:, None, :]
+    return jnp.sum(jnp.where(same, outer, 0.0), axis=(1, 2))
 
 
 # ---------------------------------------------------------------------------

@@ -39,24 +39,12 @@ from ..graphs.formats import Graph
 from .. import solvers
 from ..solvers import SolveStrategy
 
-# jax.shard_map with replication checks off, across the API move:
-# jax >= 0.6 exposes jax.shard_map(check_vma=...); 0.4/0.5 has
-# jax.experimental.shard_map.shard_map(check_rep=...).
-if hasattr(jax, "shard_map"):
-    def _shard_map(f, *, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
 
-    def _shard_map(f, *, mesh, in_specs, out_specs):
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=False)
-
-
-# The version-compat wrapper is the module's real export surface: the
-# sharded serving path (serving/sharded.py) builds on the same shim.
-shard_map_compat = _shard_map
+def shard_map_unchecked(f, *, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the replication checks off — the one shard_map
+    entry of the CG path and of sharded serving (serving/sharded.py)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _data_axes(mesh: Mesh) -> tuple[str, ...]:
@@ -151,7 +139,7 @@ def sharded_cg_solve(
     rowk = P(axes, None)
 
     @functools.partial(
-        _shard_map,
+        shard_map_unchecked,
         mesh=mesh,
         in_specs=(rowk, rowk, rowk, P(), row),
         out_specs=(row, P(), P()),
@@ -210,7 +198,7 @@ def sharded_cg_solve_chunked(
     row = P(axes)
 
     @functools.partial(
-        _shard_map,
+        shard_map_unchecked,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(), row),
         out_specs=(row, P(), P()),
@@ -269,7 +257,7 @@ def sharded_posterior_sample(
     rowk = P(axes, None)
 
     @functools.partial(
-        _shard_map,
+        shard_map_unchecked,
         mesh=mesh,
         in_specs=(rowk, rowk, rowk, P(), row, row, P()),
         out_specs=(row, P(), P()),

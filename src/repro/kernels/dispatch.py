@@ -1,20 +1,28 @@
 """Backend registry for the GRF sparse linear-algebra stack (DESIGN.md §3).
 
 Every sparse product in the codebase — ``phi_matvec`` (gather), ``phi_t_matvec``
-(scatter), the fused ``khat_matvec`` and the serving cross-Gram
-``gram_block`` — is dispatched through this registry instead of hard-coding
-an implementation at the call site.  Three backends:
+(scatter), the fused ``khat_matvec``, the serving cross-Gram ``gram_block``,
+the Nyström ``woodbury_apply`` and the ``walk_sample`` walker — is dispatched
+through this registry instead of hard-coding an implementation at the call
+site.  Three backends:
 
   * ``"xla"``              pure-jnp gather/scatter (differentiable, portable).
   * ``"pallas"``           compiled Mosaic kernels (TPU).
   * ``"pallas-interpret"`` the same kernels through the Pallas interpreter
-                           (CPU-testable bit-accurate stand-in for "pallas").
+                           (CPU-testable bit-accurate stand-in for "pallas";
+                           a test backend, refused on TPU).
 
 Resolution order: active :func:`use_backend` context > :func:`set_backend`
 global > ``REPRO_SPMV_BACKEND`` env var (how the CI backend matrix pins the
 whole suite to one backend) > auto (``"pallas"`` on TPU, ``"xla"``
 elsewhere).  Backend selection happens at Python trace time, so switching
 backends retraces but adds zero per-call overhead inside jit.
+
+On TPU, ``"pallas"`` is then narrowed per product by :func:`resolve`, a
+fixed rule: only the products in :data:`PALLAS_ON_TPU` — the kernels that
+Mosaic lowers, each compiled for v5e by tests/test_chip_compile.py — run as
+Pallas kernels; the rest run their ``"xla"`` implementation, which XLA
+compiles for the TPU.  DESIGN.md §3.3 gives the compiler's reason for each.
 
 The Pallas paths are wrapped in ``jax.custom_vjp`` (all three products are
 linear in both ``vals`` and the dense operand), so hyperparameter gradients
@@ -39,6 +47,16 @@ def _check(name: str) -> str:
     if name not in VALID_BACKENDS:
         raise ValueError(f"unknown spmv backend {name!r}; valid: {VALID_BACKENDS}")
     return name
+
+
+# Products whose Pallas kernel lowers on TPU.  ell_spmv / khat_fused (a
+# gather and scatter-adds into VMEM) and walk_sampler (a gather over the
+# VMEM-pinned adjacency) do not lower in Mosaic as written; DESIGN.md §3.3.
+PALLAS_ON_TPU = frozenset({"gram_block", "woodbury_apply"})
+PRODUCTS = (
+    "phi_matvec", "phi_t_matvec", "khat_matvec", "gram_block",
+    "woodbury_apply", "walk_sample",
+)
 
 
 def auto_backend() -> str:
@@ -75,6 +93,26 @@ def use_backend(name: str):
         _override.reset(token)
 
 
+def resolve(product: str, backend: str | None = None) -> str:
+    """The implementation ``product`` runs under ``backend`` (default: the
+    active backend) on this platform — the fixed per-product TPU rule."""
+    backend = get_backend() if backend is None else _check(backend)
+    if jax.default_backend() != "tpu":
+        return backend
+    if backend == "pallas-interpret":
+        raise ValueError("pallas-interpret is a CPU test backend; on TPU use "
+                         "'pallas' or 'xla'")
+    if backend == "pallas" and product not in PALLAS_ON_TPU:
+        return "xla"
+    return backend
+
+
+def chosen_backends() -> dict[str, str]:
+    """{product: implementation} under the active backend — what a run
+    reports as its kernel choice."""
+    return {p: resolve(p) for p in PRODUCTS}
+
+
 def _interpret(backend: str) -> bool:
     return backend == "pallas-interpret"
 
@@ -88,7 +126,7 @@ def _interpret(backend: str) -> bool:
 
 def phi_matvec(vals, cols, u, *, backend: str | None = None):
     """y = Φ u (gather-reduce)."""
-    backend = _check(backend) if backend is not None else get_backend()
+    backend = resolve("phi_matvec", backend)
     from .ell_spmv import ops
 
     if backend == "xla":
@@ -98,7 +136,7 @@ def phi_matvec(vals, cols, u, *, backend: str | None = None):
 
 def phi_t_matvec(vals, cols, v, n_nodes: int, *, backend: str | None = None):
     """u = Φᵀ v (scatter-add)."""
-    backend = _check(backend) if backend is not None else get_backend()
+    backend = resolve("phi_t_matvec", backend)
     from .ell_spmv import ops
 
     if backend == "xla":
@@ -116,7 +154,7 @@ def khat_matvec(
     the gather pass (never spilling the N-vector to HBM between the two
     products); the XLA path composes the two products.
     """
-    backend = _check(backend) if backend is not None else get_backend()
+    backend = resolve("khat_matvec", backend)
     from .ell_spmv import ops
 
     if backend == "xla":
@@ -138,7 +176,7 @@ def gram_block(
     compare-and-accumulate, never materialising anything N-long.  Handles
     duplicate deposit columns exactly, so diag(gram_block(Φ, Φ)) is the
     *exact* ‖φ(i)‖² (cf. features.khat_diag_exact)."""
-    backend = _check(backend) if backend is not None else get_backend()
+    backend = resolve("gram_block", backend)
     from .gram_block import ops
 
     if backend == "xla":
@@ -157,7 +195,7 @@ def woodbury_apply(b, dinv, einv, v, *, backend: str | None = None):
     CG solve; the kernel keeps the [r, R] rank-space intermediate and the
     r×r inverse capacitance VMEM-resident so the per-iteration apply is one
     pass instead of a chain of re-materialised XLA ops."""
-    backend = _check(backend) if backend is not None else get_backend()
+    backend = resolve("woodbury_apply", backend)
     from .woodbury_apply import ops
 
     if backend == "xla":
@@ -179,7 +217,7 @@ def walk_sample(
     variance-reduction strategy ("iid" | "antithetic" | "qmc" | "grfspp",
     DESIGN.md §3.9); like the backend it is resolved at trace time and
     rides the jit cache key as a static."""
-    backend = _check(backend) if backend is not None else get_backend()
+    backend = resolve("walk_sample", backend)
     from ..obs import taps as _obs_taps
     from .walk_sampler import ops
 

@@ -12,6 +12,11 @@ TPU adaptation of the paper's sparse-tensor product (DESIGN.md §3):
     operand, which Mosaic lowers to on-chip dynamic addressing.
 
 Grid: (M // BM,).  Per-step VMEM: BM·K·(4+4) + N·4·R + BM·4·R bytes.
+
+**Not used on TPU.**  Mosaic refuses the ``jnp.take`` ("Only 2D gather is
+supported"), and at N=10⁶, R=9 the resident operand (36 MB) would not fit
+VMEM anyway.  kernels/dispatch.py therefore runs Φu through its ``"xla"``
+implementation on TPU; this kernel is exercised through the interpreter.
 """
 from __future__ import annotations
 

@@ -9,10 +9,12 @@ roofline optimum for a memory-bound scatter (payload streamed once, output
 written once).
 
 The scatter itself is expressed as ``acc.at[cols].add(contrib)`` over the
-VMEM-resident accumulator.  Mosaic lowers small-window dynamic scatter via
-on-chip addressing; on toolchains without scatter lowering, route through
-the ``"xla"`` backend (kernels/dispatch.py) — the interpreter path used by
-tests is exact either way.
+VMEM-resident accumulator.
+
+**Not used on TPU.**  Mosaic has no lowering for the scatter-add
+("Unimplemented primitive ... scatter-add"), so kernels/dispatch.py runs
+Φᵀv through its ``"xla"`` implementation on TPU; this kernel is exercised
+through the interpreter, where it is exact.
 
 Grid: (M // BM,).  Per-step VMEM: BM·K·(4+4) + N·4·R + BM·4·R bytes.
 """

@@ -16,7 +16,9 @@ cross-covariance form K̂[rows, cols] (Eq. 12) as well as the square K̂.
 u is written to HBM zero times — it lives and dies in VMEM (N·4·R bytes;
 a 1M-node f32 vector is 4 MB < 16 MB VMEM).
 
-Scatter lowering caveat: see ell_spmv_t.py.
+**Not used on TPU.**  Phase 0 is the same scatter-add as ell_spmv_t.py,
+which Mosaic does not lower; kernels/dispatch.py composes the two ``"xla"``
+products on TPU instead.
 """
 from __future__ import annotations
 

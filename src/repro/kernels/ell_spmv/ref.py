@@ -19,9 +19,11 @@ def ell_spmv_ref(vals: jnp.ndarray, cols: jnp.ndarray, u: jnp.ndarray) -> jnp.nd
     Returns: f32[M] or f32[M, R].
     """
     gathered = u[cols]  # [M, K] or [M, K, R]
+    # Multiply-and-reduce, not an einsum: a length-K contraction per row is
+    # no matmul, and XLA:TPU may run a dot_general at bf16 input precision.
     if u.ndim == 1:
-        return jnp.einsum("mk,mk->m", vals, gathered)
-    return jnp.einsum("mk,mkr->mr", vals, gathered)
+        return jnp.sum(vals * gathered, axis=1)
+    return jnp.sum(vals[:, :, None] * gathered, axis=1)
 
 
 def ell_spmv_t_ref(

@@ -7,22 +7,21 @@ train rows.  Both operands are ELL payloads, so the product never touches
 the N-dimensional node space at all — the contraction is a masked
 compare-and-accumulate over deposit slots.
 
-Layout:
+Layout (chosen so Mosaic lowers it; DESIGN.md §3.3):
 
-  * The *train* payload (vals_cols/cols_cols, [M_x, K_x]) is pinned to block
-    0 of the grid so it stays **entirely VMEM-resident across every grid
-    step** — the capacity×K train block is a few hundred KB (e.g. 1024 rows
-    × 144 slots × 8 B ≈ 1.2 MB ≪ 16 MB VMEM), and every query block reads it
-    at on-chip latency.
-  * Query rows are tiled into BQ-row blocks streamed HBM→VMEM once.
-  * Inside the kernel a ``fori_loop`` walks the K_r query slots; each step
-    materialises one [BQ, M_x, K_x] compare block, so the live intermediate
-    is BQ·M_x·K_x·4 B (BQ=8, M_x=1024, K_x=144 → 4.7 MB) instead of the 4-D
-    [BQ, K_r, M_x, K_x] tensor.
+  * Query rows are tiled into BQ-row blocks held in **SMEM**: every
+    (row, slot) deposit is read as a scalar at a dynamic slot index, which
+    Mosaic supports where a dynamic lane slice of a vector does not.
+  * The train payload is passed transposed, [K_x, M_x], and tiled into
+    BX-column blocks in VMEM, so train rows lie on the 128 lanes and the
+    deposit slots on sublanes.
+  * Per query row a ``fori_loop`` over its K_r slots accumulates
+    ``where(cols_x == c, vals_x, 0) · v`` into one [K_x, BX] tile; a single
+    sublane reduction at the end gives the row of G.
 
-Grid: (ceil(M_r / BQ),).  Per-step VMEM:
-  M_x·K_x·8 (resident train payload) + BQ·K_r·8 (query block)
-  + BQ·M_x·(K_x + 1)·4 (compare block + output).
+Grid: (ceil(M_r / BQ), ceil(M_x / BX)).  Per-step VMEM:
+  2·K_x·BX·4 (train tile) + K_x·BX·4 (accumulator) + BQ·BX·4 (output);
+K_x=56, BX=512 → ~0.4 MB, so the kernel fits scoped VMEM at any M_x.
 """
 from __future__ import annotations
 
@@ -31,28 +30,26 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 DEFAULT_BQ = 8
+DEFAULT_BX = 512
 
 
-def _gram_kernel(vals_q_ref, cols_q_ref, vals_x_ref, cols_x_ref, out_ref):
-    vals_q = vals_q_ref[:]                   # [BQ, K_r]
-    cols_q = cols_q_ref[:]                   # [BQ, K_r]
-    vals_x = vals_x_ref[:]                   # [M_x, K_x] — VMEM-resident
-    cols_x = cols_x_ref[:]
-    k_r = vals_q.shape[1]
+def _gram_kernel(cols_q_ref, vals_q_ref, cols_xt_ref, vals_xt_ref, out_ref):
+    bq, k_r = cols_q_ref.shape
+    cols_xt = cols_xt_ref[...]               # [K_x, BX]
+    vals_xt = vals_xt_ref[...]
+    for i in range(bq):
+        def slot(k, acc, i=i):
+            hit = jnp.where(cols_xt == cols_q_ref[i, k], vals_xt, 0.0)
+            return acc + vals_q_ref[i, k] * hit
 
-    def slot(k, acc):
-        c = jax.lax.dynamic_index_in_dim(cols_q, k, axis=1)   # [BQ, 1]
-        v = jax.lax.dynamic_index_in_dim(vals_q, k, axis=1)   # [BQ, 1]
-        match = (cols_x[None, :, :] == c[:, :, None]).astype(jnp.float32)
-        contrib = jnp.sum(vals_x[None, :, :] * match, axis=2)  # [BQ, M_x]
-        return acc + v * contrib
-
-    out_ref[:] = jax.lax.fori_loop(
-        0, k_r, slot, jnp.zeros(out_ref.shape, jnp.float32)
-    )
+        acc = jax.lax.fori_loop(
+            0, k_r, slot, jnp.zeros(vals_xt.shape, jnp.float32)
+        )
+        out_ref[i:i + 1, :] = jnp.sum(acc, axis=0, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "interpret"))
@@ -68,29 +65,29 @@ def gram_block(
     """G = Φ_rows Φ_colsᵀ ∈ R^{M_r × M_c}.  See ref.py for semantics."""
     mr, kr = vals_rows.shape
     mx, kx = vals_cols.shape
+    bq = block_q
+    bx = min(DEFAULT_BX, -(-mx // 128) * 128)
+    pad_r = (-mr) % bq
+    pad_x = (-mx) % bx
+    # Zero vals ⇒ padded rows on either side produce zero Gram entries.
+    vals_q = jnp.pad(vals_rows.astype(jnp.float32), ((0, pad_r), (0, 0)))
+    cols_q = jnp.pad(cols_rows, ((0, pad_r), (0, 0)))
+    vals_xt = jnp.pad(vals_cols.astype(jnp.float32), ((0, pad_x), (0, 0))).T
+    cols_xt = jnp.pad(cols_cols, ((0, pad_x), (0, 0))).T
+    mp, xp = mr + pad_r, mx + pad_x
 
-    bq = min(block_q, max(8, mr))
-    pad = (-mr) % bq
-    if pad:
-        # Zero vals ⇒ padded query rows produce zero Gram rows.
-        vals_rows = jnp.pad(vals_rows, ((0, pad), (0, 0)))
-        cols_rows = jnp.pad(cols_rows, ((0, pad), (0, 0)))
-    mp = mr + pad
-
+    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
     y = pl.pallas_call(
         _gram_kernel,
-        grid=(mp // bq,),
+        grid=(mp // bq, xp // bx),
         in_specs=[
-            pl.BlockSpec((bq, kr), lambda i: (i, 0)),
-            pl.BlockSpec((bq, kr), lambda i: (i, 0)),
-            pl.BlockSpec((mx, kx), lambda i: (0, 0)),
-            pl.BlockSpec((mx, kx), lambda i: (0, 0)),
+            smem((bq, kr), lambda i, j: (i, 0)),
+            smem((bq, kr), lambda i, j: (i, 0)),
+            pl.BlockSpec((kx, bx), lambda i, j: (0, j)),
+            pl.BlockSpec((kx, bx), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bq, mx), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((mp, mx), jnp.float32),
+        out_specs=pl.BlockSpec((bq, bx), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((mp, xp), jnp.float32),
         interpret=interpret,
-    )(
-        vals_rows.astype(jnp.float32), cols_rows,
-        vals_cols.astype(jnp.float32), cols_cols,
-    )
-    return y[:mr] if pad else y
+    )(cols_q, vals_q, cols_xt, vals_xt)
+    return y[:mr, :mx]

@@ -85,8 +85,15 @@ def counter_bits(seed, node, walker, ctr) -> jnp.ndarray:
 
 def counter_uniform(seed, node, walker, ctr) -> jnp.ndarray:
     """f32 uniform in [0, 1) from the top 24 bits of the counter hash."""
-    bits = counter_bits(seed, node, walker, ctr)
-    return (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(_INV_2_24)
+    return _top24_uniform(counter_bits(seed, node, walker, ctr))
+
+
+def _top24_uniform(bits: jnp.ndarray) -> jnp.ndarray:
+    """f32 in [0, 1) from the top 24 bits of a uint32.  The shifted value
+    fits in 24 bits, so the hop through int32 is exact; Mosaic has no
+    uint32 → float32 cast."""
+    top = (bits >> jnp.uint32(8)).astype(jnp.int32)
+    return top.astype(jnp.float32) * jnp.float32(_INV_2_24)
 
 
 def bitrev32(x: jnp.ndarray) -> jnp.ndarray:
@@ -125,6 +132,5 @@ def halt_uniform(seed, node, walker, ctr, *, scheme: str) -> jnp.ndarray:
         # XOR shift from the counter chain (Owen-style digital shift).
         shift = counter_bits(seed, node, jnp.uint32(_QMC_SALT), ctr)
         bits = bitrev32(walker) ^ shift
-        return (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(
-            _INV_2_24)
+        return _top24_uniform(bits)
     raise ValueError(f"unknown walk scheme {scheme!r}; valid: {SCHEMES}")

@@ -22,6 +22,12 @@ orthogonal and works on every backend.
 
 The step math itself is ref.walk_block — the kernel and the jnp oracle
 evaluate the same function, so parity is exact, not statistical.
+
+**Not used on TPU.**  Mosaic refuses the neighbour ``jnp.take`` ("Only 2D
+gather is supported"), and the resident adjacency of ``ring(10⁶, k=3)`` is
+52 MB against 16 MB of VMEM.  kernels/dispatch.py runs walk sampling
+through its ``"xla"`` implementation on TPU; the kernel is exercised through
+the interpreter.
 """
 from __future__ import annotations
 

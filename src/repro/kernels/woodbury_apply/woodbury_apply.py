@@ -17,10 +17,14 @@ iteration; this kernel is one pass in the khat_fused two-phase shape:
                       block then emits  out = D⁻¹v − D⁻¹(B s)  fused with
                       the diagonal scale and residual subtraction.
 
+D⁻¹ enters as a [T, 1] column: a 1-D block is tiled differently by XLA
+(T(1024)) and Mosaic (T(512)), and the chip's compiler refuses the
+mismatch; a 2-D [BT, 1] block has one layout on both sides.
+
 Grid: (2, NB), NB = ceil(T / BT).  Per-step VMEM:
   BT·r·4 (factor block) + r·(R + r)·4 (scratch + resident E⁻¹)
-  + BT·(2R + 1)·4 (v/out blocks + D⁻¹ block);
-BT=512, r=256, R=9 → ~0.8 MB ≪ 16 MB VMEM, so the tile budget is set by
+  + BT·2R·4 (v/out blocks) + BT·128·4 (D⁻¹ block, lane-padded);
+BT=512, r=256, R=9 → ~1 MB ≪ 16 MB VMEM, so the tile budget is set by
 the factor block — r=256 leaves room for BT up to ~7k rows.  E⁻¹ rides the
 same BlockSpec trick as gram_block's train payload (index map pinned to
 block 0) so it is fetched once and stays VMEM-resident across the grid.
@@ -36,6 +40,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 DEFAULT_BT = 512
+# f32 MXU products at full precision (the default may round inputs to bf16).
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _woodbury_kernel(b_ref, dinv_ref, einv_ref, v_ref, out_ref, s_ref):
@@ -48,9 +54,10 @@ def _woodbury_kernel(b_ref, dinv_ref, einv_ref, v_ref, out_ref, s_ref):
 
     @pl.when(phase == 0)
     def _reduce():
-        w = dinv_ref[:][:, None] * v_ref[:]            # [BT, R]
+        w = dinv_ref[:] * v_ref[:]                      # [BT, R]
         s_ref[:] += jnp.dot(
-            b_ref[:].T, w, preferred_element_type=jnp.float32
+            b_ref[:].T, w, preferred_element_type=jnp.float32,
+            precision=_HIGHEST
         )                                               # [r, R]
         # Placeholder so every out block holds defined values; phase 1
         # revisits the same block index and overwrites with the result.
@@ -59,14 +66,16 @@ def _woodbury_kernel(b_ref, dinv_ref, einv_ref, v_ref, out_ref, s_ref):
     @pl.when((phase == 1) & (block == 0))
     def _capacitance():
         s_ref[:] = jnp.dot(
-            einv_ref[:], s_ref[:], preferred_element_type=jnp.float32
+            einv_ref[:], s_ref[:], preferred_element_type=jnp.float32,
+            precision=_HIGHEST
         )
 
     @pl.when(phase == 1)
     def _expand():
-        dinv = dinv_ref[:][:, None]                     # [BT, 1]
+        dinv = dinv_ref[:]                              # [BT, 1]
         bs = jnp.dot(
-            b_ref[:], s_ref[:], preferred_element_type=jnp.float32
+            b_ref[:], s_ref[:], preferred_element_type=jnp.float32,
+            precision=_HIGHEST
         )                                               # [BT, R]
         out_ref[:] = dinv * (v_ref[:] - bs)
 
@@ -102,7 +111,7 @@ def woodbury_apply(
         grid=(2, tp // bt),
         in_specs=[
             pl.BlockSpec((bt, r), lambda p, i: (i, 0)),
-            pl.BlockSpec((bt,), lambda p, i: (i,)),
+            pl.BlockSpec((bt, 1), lambda p, i: (i, 0)),
             pl.BlockSpec((r, r), lambda p, i: (0, 0)),
             pl.BlockSpec((bt, rhs), lambda p, i: (i, 0)),
         ],
@@ -111,7 +120,7 @@ def woodbury_apply(
         scratch_shapes=[pltpu.VMEM((r, rhs), jnp.float32)],
         interpret=interpret,
     )(
-        b.astype(jnp.float32), dinv.astype(jnp.float32),
+        b.astype(jnp.float32), dinv.astype(jnp.float32)[:, None],
         einv.astype(jnp.float32), v.astype(jnp.float32),
     )
     y = y[:t] if pad else y

@@ -33,17 +33,10 @@ from contextvars import ContextVar
 
 import jax
 
+from ..runtime import trace_state_clean
 from . import registry
 
 _stack: ContextVar[tuple[str, ...]] = ContextVar("repro_obs_spans", default=())
-
-
-def _trace_state_clean() -> bool:
-    """True when no jax trace is active (host wall-clock is meaningful)."""
-    try:
-        return jax.core.trace_state_clean()
-    except AttributeError:  # moved across jax versions; fail open
-        return True
 
 
 class Span:
@@ -94,7 +87,7 @@ def span(name: str, *, block=None, **attrs):
     jax trace: span wall-clock is host time, which is meaningless while
     tracing (an instrumented eager driver called from inside someone else's
     jit must not record trace time as a span)."""
-    if not registry.enabled() or not _trace_state_clean():
+    if not registry.enabled() or not trace_state_clean():
         yield _NULL
         return
     parent = _stack.get()

@@ -47,7 +47,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from .. import obs
 from ..core import features
 from ..core.walks import WalkTrace
-from ..distributed.gp_shard import psum_reduce, shard_map_compat
+from ..distributed.gp_shard import psum_reduce, shard_map_unchecked
 from ..kernels import dispatch
 from ..launch.mesh import make_serving_mesh
 from ..resilience import faults
@@ -101,7 +101,7 @@ def _sharded_cross(state: ServeState, qnodes: jax.Array, mesh, axis: str):
 
     spec_state = _state_specs(state, axis)
     trace_spec = WalkTrace(cols=P(), loads=P(), lens=P())
-    return shard_map_compat(
+    return shard_map_unchecked(
         run, mesh=mesh,
         in_specs=(spec_state, P(axis)),
         out_specs=(P(), trace_spec),

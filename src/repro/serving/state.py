@@ -217,7 +217,8 @@ def _mean_whiten(state: ServeState, k_qx: jax.Array):
     """mean[q] and the whitened cross-block v = L⁻¹ K̂_{x,q} [c, q] from a
     cross-Gram row block — shared verbatim by the single-device and sharded
     paths, so their downstream math is bit-identical once k_qx agrees."""
-    mean = k_qx @ state.alpha
+    # HIGHEST: at default precision a TPU may round f32 operands to bf16.
+    mean = jnp.dot(k_qx, state.alpha, precision=jax.lax.Precision.HIGHEST)
     v = solve_triangular(state.chol, k_qx.T, lower=True)  # [capacity, q]
     return mean, v
 

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 
 from .. import obs
 from ..resilience import faults
+from ..runtime import trace_state_clean
 from .cg import CGResult
 from .cg import solve as _base_solve
 from .strategy import SolveStrategy
@@ -91,7 +92,7 @@ def solve_escalate(
     the first attempt only; later rungs rebuild per their own strategy.
     ``backoff`` is the base of the jittered exponential host sleep between
     attempts (seconds)."""
-    if not jax.core.trace_state_clean():
+    if not trace_state_clean():
         # Mid-trace there are no concrete converged flags to branch on —
         # run the caller's strategy once, exactly as without escalation.
         return _base_solve(

@@ -64,6 +64,7 @@ from jax.scipy.linalg import cho_solve
 
 from ..core import features, linops
 from ..kernels import dispatch
+from ..runtime import trace_state_clean
 from .strategy import AUTO_RANKS, DEFAULT_PRECOND_RANK, SolveStrategy
 
 
@@ -333,7 +334,7 @@ def resolve_strategy(
     # Under an active trace even closed-over concrete operands produce
     # tracers the moment the probe touches them, so "am I inside jit" is the
     # test — not "are the leaves tracers".
-    tracing = not jax.core.trace_state_clean() or any(
+    tracing = not trace_state_clean() or any(
         isinstance(leaf, jax.core.Tracer)
         for leaf in jax.tree_util.tree_leaves(h)
     )
