@@ -1,0 +1,66 @@
+"""Inputs made from a configuration and ``--seed``: graph, walks, keys,
+signals.  The program receives only what these build."""
+from __future__ import annotations
+
+import sys
+import time
+
+import numpy as np
+
+_T0 = time.perf_counter()
+
+
+def log(msg: str) -> None:
+    """A progress line on standard error, with seconds since import."""
+    print(f"[{time.perf_counter() - _T0:8.2f}s] {msg}", file=sys.stderr,
+          flush=True)
+
+
+def prng_key(seed: int):
+    """A JAX key from a seed of any size (``PRNGKey`` keeps only the low
+    32 bits, so the high bits are folded in)."""
+    import jax
+
+    key = jax.random.PRNGKey(seed & 0xFFFFFFFF)
+    return jax.random.fold_in(key, (seed >> 32) & 0x7FFFFFFF)
+
+
+def np_rng(seed: int, salt: int = 0) -> np.random.Generator:
+    return np.random.default_rng([salt, seed])
+
+
+def build_graph(spec: dict):
+    """``repro.graphs.generators.<generator>(**params)``, on the device."""
+    import jax
+    from repro.graphs import generators
+
+    params = {k: v for k, v in spec.items() if k != "generator"}
+    graph = jax.block_until_ready(
+        getattr(generators, spec["generator"])(**params))
+    log(f"graph {spec['generator']} {params}: {graph.n_nodes} nodes, "
+        f"max degree {graph.max_deg}")
+    return graph
+
+
+def walk_config(spec: dict):
+    from repro.core import walks
+
+    return walks.WalkConfig(**spec)
+
+
+def modulation(spec: dict, l_max: int):
+    from repro.core import modulation as mod
+
+    return mod.REGISTRY[spec["name"]](l_max=l_max)
+
+
+def signal(spec: dict, seed: int) -> np.ndarray:
+    """Ground truth over all nodes: ``repro.graphs.signals.<name>``, its
+    random draws keyed on the configuration's ``seed`` where it fixes the
+    deployment's data, else on the run's ``seed``."""
+    from repro.graphs import signals
+
+    params = {k: v for k, v in spec.items()
+              if k not in ("name", "noise_std", "seed")}
+    return np.asarray(getattr(signals, spec["name"])(
+        **params, seed=spec.get("seed", seed) % (2**32)), np.float64)

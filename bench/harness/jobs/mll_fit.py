@@ -1,0 +1,175 @@
+"""Repeated hyperparameter fits (paper §3.2) through ``mll.fit_hyperparams``.
+
+Set-up samples Φ_x for the observed block once and runs one jitted chunk,
+which compiles the fit's program.  The window runs whole fits, each from
+the same seeded initial hyperparameters, until ``--seconds`` have passed.
+
+The first fit of the window is checked: the float64 reference follows its
+first chunk of Adam steps with exact (Cholesky) solves of the same
+surrogate, the same probes and the same optimiser, and the run compares
+each of the first three steps' loss and, leaf by leaf, the parameters'
+change over the chunk.
+"""
+from __future__ import annotations
+
+import time
+
+import numpy as np
+
+from harness import data, reference
+from harness.trace import annotate
+
+
+class Job:
+    def __init__(self, config: dict, traffic: dict, seed: int,
+                 matvec_dtype: str = "float32"):
+        self.config, self.traffic, self.seed = config, traffic, seed
+        self.matvec_dtype = matvec_dtype
+        self.counts: dict = {}
+        self.latencies: list = []
+        self.first_chunk = None
+
+    def setup(self) -> None:
+        import jax
+        import jax.numpy as jnp
+        from repro import solvers
+        from repro.core import walks
+        from repro.gp import mll
+
+        cfg, fit = self.config, self.traffic["fit"]
+        graph = data.build_graph(cfg["graph"])
+        self.walk = data.walk_config(cfg["walks"])
+        self.mod = data.modulation(cfg["modulation"], self.walk.l_max)
+        self.n = graph.n_nodes
+        t = cfg["n_train"]
+        # The deployment's data (targets with their noise), its random
+        # features (the walks) and the fit's Hutchinson probes are fixed
+        # where the configuration and the mix name their seeds, so that
+        # every run fits the same problem: the probes alone move a fit's
+        # CG iterations, and with them its time, by about half a percent.
+        data_seed = cfg["targets"].get("seed", self.seed)
+        rng = data.np_rng(data_seed)
+        self.train = (cfg["train_start"] + np.arange(t)) % self.n
+        truth = data.signal(cfg["targets"], self.seed)
+        self.y = (truth[self.train] + cfg["targets"]["noise_std"]
+                  * rng.standard_normal(t)).astype(np.float32)
+        self.walk_key = jax.random.fold_in(
+            data.prng_key(cfg.get("walk_seed", self.seed)), 1)
+        self.fit_key = jax.random.fold_in(
+            data.prng_key(fit.get("probe_seed", self.seed)), 2)
+        w = self.walk
+        self.trace_x = walks.sample_walks_for_nodes(
+            graph, jnp.asarray(self.train, jnp.int32), self.walk_key,
+            w.n_walkers, w.p_halt, w.l_max, w.reweight, w.scheme)
+        del graph
+        self.strategy = solvers.MLL_DEFAULT.with_(
+            matvec_dtype=self.matvec_dtype)
+        self.mll = mll
+        self.y_dev = jnp.asarray(self.y)
+
+        # The first chunk of the window's first fit is kept for the check;
+        # the wrapper leaves the compiled program unchanged.
+        original = mll._fit_chunk
+        self._original_chunk = original
+
+        def recorded_chunk(params, opt_state, key_c, *args, **kw):
+            with annotate("bench.fit_chunk"):
+                out = original(params, opt_state, key_c, *args, **kw)
+            if self.recording and self.first_chunk is None:
+                self.first_chunk = dict(params=out[0], traces=out[3])
+            return out
+
+        mll._fit_chunk = recorded_chunk
+        self.recording = False
+        data.log("Phi_x sampled; warm-up chunk")
+        # Warm-up: one chunk compiles the fit's only program.
+        self._fit(fit["chunk"])
+        data.log("warm-up done")
+
+    def _fit(self, steps: int):
+        fit = self.traffic["fit"]
+        with annotate("bench.fit"):
+            return self.mll.fit_hyperparams(
+                self.trace_x, self.mod, self.y_dev, self.n, self.fit_key,
+                steps=steps, lr=fit["lr"], n_probes=fit["n_probes"],
+                chunk=fit["chunk"], init_noise=fit["init"]["sigma_n"],
+                strategy=self.strategy)
+
+    def window(self, seconds: float) -> None:
+        steps = self.traffic["fit"]["steps"]
+        self.recording = True
+        self.histories = []
+        t0 = time.perf_counter()
+        while True:
+            t_fit = time.perf_counter()
+            res = self._fit(steps)
+            self.latencies.append(time.perf_counter() - t_fit)
+            self.histories.append(res.history)
+            if time.perf_counter() - t0 >= seconds:
+                break
+        self.window_s = time.perf_counter() - t0
+        self.recording = False
+        hist = [h for fit_h in self.histories for h in fit_h]
+        self.counts = {
+            "fits": len(self.histories),
+            "steps": len(hist),
+            "cg_iters": sum(h["cg_iters"] for h in hist),
+            "cg_nonconverged": sum(not h["cg_converged"] for h in hist),
+            "rows": int(self.trace_x.cols.shape[0]),
+            "slots": int(self.trace_x.cols.shape[1]),
+            "rhs": 1 + self.traffic["fit"]["n_probes"],
+        }
+        self.attempted = len(hist)
+        self.failed = self.counts["cg_nonconverged"]
+
+    def release(self) -> None:
+        self.mll._fit_chunk = self._original_chunk
+        self.first = dict(
+            params=reference.theta(self.first_chunk["params"]),
+            losses=[h["loss"] for h in self.histories[0]],
+        )
+        self.trace_x = None
+
+    # -- correctness -------------------------------------------------------
+    def check(self) -> list:
+        loss_gap, param_gap = self.gaps()
+        lim = self.traffic["check"]["limits"]
+        return [("loss_gap", loss_gap, float(lim["loss_gap"])),
+                ("param_gap", param_gap, float(lim["param_gap"]))]
+
+    def gaps(self):
+        """(widest loss gap over the first three steps, as a share of the
+        size of the surrogate's two terms; the worst leaf's gap of the
+        parameter change over the first chunk)."""
+        ref = self.reference_chunk()
+        n_loss = self.traffic["check"]["loss_steps"]
+        loss_gap = max(abs(p - r) / s for p, r, s in zip(
+            self.first["losses"][:n_loss], ref["losses"][:n_loss],
+            ref["scales"][:n_loss]))
+        param_gap = reference.leaf_gap(ref["init"], self.first["params"],
+                                       ref["params"], ref["grad0"])
+        return float(loss_gap), param_gap
+
+    def reference_chunk(self) -> dict:
+        """The first chunk's Adam steps on the exact surrogate, float64."""
+        import jax
+
+        cfg, fit = self.config, self.traffic["fit"]
+        wk = cfg["walks"]
+        adj = reference.Adjacency.from_spec(cfg["graph"])
+        seed_u32 = int(np.asarray(jax.random.bits(self.walk_key, (),
+                                                  np.uint32)))
+        rows = reference.walks(adj, self.train, seed_u32, wk["n_walkers"],
+                               wk["p_halt"], wk["l_max"])
+        t = len(self.train)
+        _, k_loop = jax.random.split(self.fit_key)
+        z = np.asarray(jax.random.bernoulli(
+            jax.random.fold_in(k_loop, 0), 0.5, (t, fit["n_probes"])),
+            np.float64) * 2.0 - 1.0
+        init = fit["init"]
+        theta0 = np.array([init["log_beta"], init["log_sigma_f"],
+                           np.log(init["sigma_n"])])
+        ref = reference.adam_fit(rows, adj.n_nodes, self.y, z, np.ones(t),
+                                 theta0, fit["lr"], fit["chunk"],
+                                 wk["l_max"])
+        return dict(ref, init=theta0)

@@ -1,0 +1,78 @@
+"""Resolve a cell of ``BENCHMARK.json`` to its files.
+
+Everything that belongs to one configuration, traffic mix or metric sits
+in a file of its own, found by the name ``BENCHMARK.json`` gives it:
+
+  * a configuration: the ``file`` of its ``configs`` entry;
+  * a traffic mix: ``bench/traffic/<traffic>.json``, whose ``job`` key
+    names the kind of job under ``bench/harness/jobs/``;
+  * a metric: ``bench/metrics/<metric name>.py``, with ``read(run)``.
+"""
+from __future__ import annotations
+
+import dataclasses
+import importlib.util
+import json
+import os
+
+BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH_DIR)
+
+
+@dataclasses.dataclass
+class Cell:
+    name: str
+    chips: int
+    config: dict
+    traffic: dict
+    end_to_end: list             # metric entries reported by --trace 0
+    per_layer: list              # metric entries reported by --trace 1
+
+
+def load_json(path: str) -> dict:
+    with open(path) as fh:
+        return json.load(fh)
+
+
+def _applies(metric: dict, cell: str) -> bool:
+    return "workloads" not in metric or cell in metric["workloads"]
+
+
+def resolve(workload: str, root: str | None = None) -> Cell:
+    root = ROOT if root is None else root
+    bench = load_json(os.path.join(root, "BENCHMARK.json"))
+    cells = {w["name"]: w for w in bench["workloads"]}
+    if workload not in cells:
+        raise KeyError(f"unknown workload {workload!r}; known: {sorted(cells)}")
+    w = cells[workload]
+    configs = {c["name"]: c for c in bench["configs"]}
+    config = load_json(os.path.join(root, configs[w["config"]]["file"]))
+    traffic = load_json(os.path.join(root, os.path.basename(BENCH_DIR),
+                                     "traffic", f"{w['traffic']}.json"))
+    return Cell(
+        name=workload,
+        chips=int(w["chips"]),
+        config=config,
+        traffic=traffic,
+        end_to_end=[m for m in bench["end_to_end"] if _applies(m, workload)],
+        per_layer=[m for m in bench["per_layer"] if _applies(m, workload)],
+    )
+
+
+def _load_module(path: str, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def reader(metric: str):
+    """The ``read(run)`` function of ``bench/metrics/<metric>.py``."""
+    path = os.path.join(BENCH_DIR, "metrics", f"{metric}.py")
+    return _load_module(path, "bench_metric_" + metric.replace(".", "_")).read
+
+
+def job(kind: str):
+    """The module of a kind of job (``bench/harness/jobs/<kind>.py``)."""
+    path = os.path.join(BENCH_DIR, "harness", "jobs", f"{kind}.py")
+    return _load_module(path, "bench_job_" + kind)

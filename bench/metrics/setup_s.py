@@ -1,0 +1,5 @@
+"""Set-up seconds: process start to the end of warm-up (host clock)."""
+
+
+def read(run):
+    return run.setup_s
