@@ -125,13 +125,10 @@ def _record_round(state: BOState, picks, ys, f_max, checkpoint_cb, t):
         state.x_buf[state.count] = x_t
         state.y_buf[state.count] = float(y_t)
         state.count += 1
-    obs.inc("bo.observations", len(picks))
-    obs.inc("bo.rounds")
     if f_max is not None:
         regret = float(f_max - state.y_obs.max())
         state.regret.append(regret)
         obs.gauge("bo.incumbent_regret", regret)
-    obs.gauge("bo.incumbent_best", float(state.y_obs.max()))
     state.iteration = t + 1
     if checkpoint_cb is not None:
         checkpoint_cb(state)
@@ -200,70 +197,80 @@ def thompson_sampling(
     mask_np = np.zeros(capacity, dtype=np.float32)
 
     for t in range(state.iteration, n_steps):
-        mask_np[:] = 0.0
-        mask_np[: state.count] = 1.0
-        mask = jnp.asarray(mask_np)
-        x_all = jnp.asarray(state.x_buf)
-        y_live = state.y_buf[: state.count]
-        ymean = float(y_live.mean())
-        ystd = float(y_live.std()) + 1e-8
-        y_n = jnp.asarray((state.y_buf - ymean) / ystd) * mask
+        with obs.span("bo.round", round=t):
+            mask_np[:] = 0.0
+            mask_np[: state.count] = 1.0
+            mask = jnp.asarray(mask_np)
+            x_all = jnp.asarray(state.x_buf)
+            y_live = state.y_buf[: state.count]
+            ymean = float(y_live.mean())
+            ystd = float(y_live.std()) + 1e-8
+            y_n = jnp.asarray((state.y_buf - ymean) / ystd) * mask
 
-        if t % refit_every == 0:
-            if chunked:
-                # Φ_x rows via the counter RNG — identical to take_rows on
-                # the (never materialised) full trace.
-                trace_x = walks.sample_walks_for_nodes(
-                    graph, x_all, walk_key,
-                    walk.n_walkers, walk.p_halt, walk.l_max, walk.reweight,
-                    walk.scheme,
-                )
-            else:
-                trace_x = features.take_rows(trace, x_all)
-            if "auto" in (fit_strategy.preconditioner,
-                          sample_strategy.preconditioner):
-                # Resolve "auto" ONCE per run, on the first refit round's
-                # operator — T is the static buffer capacity and later
-                # rounds only flip mask slots, so the measured rank keeps
-                # its meaning; re-probing every round would re-pay the
-                # measurement for nothing.
-                h0 = mll.make_h_operator(
-                    trace_x, mod(state.params["mod"]),
-                    jnp.where(mask > 0, mll.noise_var(state.params), 1e6), n,
-                )
-                fit_strategy = solvers.resolve_strategy(h0, fit_strategy)
-                sample_strategy = solvers.resolve_strategy(
-                    h0, sample_strategy
-                )
-            res = mll.fit_hyperparams(
-                trace_x, mod, y_n, n, jax.random.fold_in(key, 1000 + t),
-                steps=refit_steps, lr=0.05, init_params=state.params,
-                init_noise=noise_std, obs_mask=mask, chunk=refit_steps,
-                strategy=fit_strategy,
-            )
-            state.params = res.params
+            if t % refit_every == 0:
+                with obs.span("bo.refit"):
+                    if chunked:
+                        # Φ_x rows via the counter RNG — identical to
+                        # take_rows on the (never materialised) full trace.
+                        trace_x = walks.sample_walks_for_nodes(
+                            graph, x_all, walk_key, walk.n_walkers,
+                            walk.p_halt, walk.l_max, walk.reweight,
+                            walk.scheme,
+                        )
+                    else:
+                        trace_x = features.take_rows(trace, x_all)
+                    if "auto" in (fit_strategy.preconditioner,
+                                  sample_strategy.preconditioner):
+                        # Resolve "auto" ONCE per run, on the first refit
+                        # round's operator — T is the static buffer capacity
+                        # and later rounds only flip mask slots, so the
+                        # measured rank keeps its meaning; re-probing every
+                        # round would re-pay the measurement for nothing.
+                        h0 = mll.make_h_operator(
+                            trace_x, mod(state.params["mod"]),
+                            jnp.where(mask > 0, mll.noise_var(state.params),
+                                      1e6),
+                            n,
+                        )
+                        fit_strategy = solvers.resolve_strategy(
+                            h0, fit_strategy
+                        )
+                        sample_strategy = solvers.resolve_strategy(
+                            h0, sample_strategy
+                        )
+                    res = mll.fit_hyperparams(
+                        trace_x, mod, y_n, n,
+                        jax.random.fold_in(key, 1000 + t),
+                        steps=refit_steps, lr=0.05, init_params=state.params,
+                        init_noise=noise_std, obs_mask=mask, chunk=refit_steps,
+                        strategy=fit_strategy,
+                    )
+                    state.params = res.params
 
-        f = mod(state.params["mod"])
-        s2 = mll.noise_var(state.params)
-        with obs.span("bo.draw", round=t, mode="pathwise") as sp:
-            if chunked:
-                samples = posterior.pathwise_samples_chunked(
-                    graph, x_all, f, s2, y_n, jax.random.fold_in(key, t),
-                    walk_key, walk, chunk=chunk, n_samples=batch_size,
-                    obs_mask=mask, strategy=sample_strategy,
-                )
-            else:
-                samples = posterior.pathwise_samples(
-                    trace, x_all, f, s2, y_n,
-                    jax.random.fold_in(key, t), n_samples=batch_size,
-                    obs_mask=mask, strategy=sample_strategy,
-                )
-            sp.block_on(samples)
-        # Mask observed nodes, pick one argmax per sample (Alg. 3 line 8).
-        picks = _argmax_picks(np.array(samples), np.arange(n), state.x_obs,
-                              batch_size)
-        ys = np.asarray(objective(np.array(picks)), dtype=np.float32)
-        _record_round(state, picks, ys, f_max, checkpoint_cb, t)
+            f = mod(state.params["mod"])
+            s2 = mll.noise_var(state.params)
+            with obs.span("bo.draw", round=t, mode="pathwise") as sp:
+                if chunked:
+                    samples = posterior.pathwise_samples_chunked(
+                        graph, x_all, f, s2, y_n, jax.random.fold_in(key, t),
+                        walk_key, walk, chunk=chunk, n_samples=batch_size,
+                        obs_mask=mask, strategy=sample_strategy,
+                    )
+                else:
+                    samples = posterior.pathwise_samples(
+                        trace, x_all, f, s2, y_n,
+                        jax.random.fold_in(key, t), n_samples=batch_size,
+                        obs_mask=mask, strategy=sample_strategy,
+                    )
+                sp.block_on(samples)
+            # Mask observed nodes, pick one argmax per sample (Alg. 3 line 8).
+            # Unblocked draws finish on the device inside bo.select.
+            with obs.span("bo.select"):
+                picks = _argmax_picks(np.array(samples), np.arange(n),
+                                      state.x_obs, batch_size)
+            with obs.span("bo.evaluate"):
+                ys = np.asarray(objective(np.array(picks)), dtype=np.float32)
+            _record_round(state, picks, ys, f_max, checkpoint_cb, t)
     return state
 
 

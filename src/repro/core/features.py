@@ -28,7 +28,8 @@ def feature_values(trace: WalkTrace, f: jax.Array) -> jax.Array:
     """vals[i,k] = loads[i,k] * f[lens[i,k]] — the GRF entries (Alg. 1 line 8).
 
     Supports compact traces (bf16 loads / int8 lens): math happens in f32."""
-    return trace.loads.astype(f.dtype) * f[trace.lens.astype(jnp.int32)]
+    with jax.named_scope(dispatch.PAYLOAD_SCOPE):
+        return trace.loads.astype(f.dtype) * f[trace.lens.astype(jnp.int32)]
 
 
 def phi_matvec(trace: WalkTrace, f: jax.Array, u: jax.Array) -> jax.Array:
@@ -126,7 +127,8 @@ def _sample_chunk_vals(graph, f, seed, start, chunk, n_rows, cfg):
         n_walkers=cfg.n_walkers, p_halt=cfg.p_halt, l_max=cfg.l_max,
         reweight=cfg.reweight, scheme=cfg.scheme,
     )
-    vals = (loads * valid[:, None]).astype(f.dtype) * f[lens]
+    with jax.named_scope(dispatch.PAYLOAD_SCOPE):
+        vals = (loads * valid[:, None]).astype(f.dtype) * f[lens]
     return cols, vals
 
 

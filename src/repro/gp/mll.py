@@ -272,14 +272,8 @@ def fit_hyperparams(
                    "cg_iters": int(iters_t[j]),
                    "cg_converged": bool(conv_t[j])}
             history.append(rec)
-            # Per-step diagnostics live in the registry (and the flight
-            # record), not only in the returned history array.
-            obs.gauge("mll.loss", rec["loss"])
-            obs.gauge("mll.sigma_n2", rec["sigma_n2"])
-            obs.observe("mll.cg_iters", rec["cg_iters"])
-            obs.inc("mll.steps")
-            if not rec["cg_converged"]:
-                obs.inc("mll.cg_nonconverged")
+            # Per-step diagnostics go to the flight record, not only into
+            # the returned history array.
             obs.emit_event({"type": "fit_step", **rec})
         done += this
     return FitResult(params=params, history=history)

@@ -27,15 +27,23 @@ compiles for the TPU.  DESIGN.md §3.3 gives the compiler's reason for each.
 The Pallas paths are wrapped in ``jax.custom_vjp`` (all three products are
 linear in both ``vals`` and the dense operand), so hyperparameter gradients
 flow through the kernels — the XLA backend is never silently required.
+
+Each product runs under a ``jax.named_scope`` of its own (:data:`SCOPES`),
+so every call site's device operations carry the product's name in their
+op metadata, and a profile can sum device time per product.  Scopes are
+trace-time metadata: they change no operation and cost nothing at run
+time.
 """
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
 from contextvars import ContextVar
 
 import jax
 import numpy as np
+from jax._src import cache_key as _cache_key
 
 VALID_BACKENDS = ("xla", "pallas", "pallas-interpret")
 
@@ -57,6 +65,50 @@ PRODUCTS = (
     "phi_matvec", "phi_t_matvec", "khat_matvec", "gram_block",
     "woodbury_apply", "walk_sample",
 )
+
+
+# The name scope each product's device operations carry (DESIGN.md §3.10).
+# No scope is named after a function of the program, so a match on one
+# never catches an unrelated op.
+SCOPES = {
+    "walk_sample": "grf_walks",
+    "phi_matvec": "grf_phi",
+    "phi_t_matvec": "grf_phi_t",
+    "khat_matvec": "grf_khat",
+    "gram_block": "grf_gram",
+    "woodbury_apply": "grf_woodbury",
+}
+# The ELL payload vals = loads · f[lens] (core/features.py).
+PAYLOAD_SCOPE = "grf_payload"
+# The serving factor's triangular solves, and its row append and rank-1
+# downdate (serving/state.py, serving/update.py).
+CHOL_SOLVE_SCOPE = "chol_solve"
+CHOL_UPDATE_SCOPE = "chol_update"
+NAMED_SCOPES = (*SCOPES.values(), PAYLOAD_SCOPE, CHOL_SOLVE_SCOPE,
+                CHOL_UPDATE_SCOPE)
+
+
+def _cache_key_names() -> str:
+    return "name scopes: " + ",".join(NAMED_SCOPES)
+
+
+# The persistent compilation cache keys a program with its debug info
+# stripped, and a name scope is debug info: an executable compiled by a
+# version of the program that named its work otherwise would be served, and
+# profiled, under the old names.  So the names go into every cache key.
+_cache_key.custom_hook = _cache_key_names
+
+
+def scoped(name: str):
+    """Decorator: run the function under ``jax.named_scope(name)``, entered
+    afresh on each call (one named_scope object is not re-entrant)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def run_scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return run_scoped
+    return wrap
 
 
 def auto_backend() -> str:
@@ -124,6 +176,7 @@ def _interpret(backend: str) -> bool:
 # ---------------------------------------------------------------------------
 
 
+@scoped(SCOPES["phi_matvec"])
 def phi_matvec(vals, cols, u, *, backend: str | None = None):
     """y = Φ u (gather-reduce)."""
     backend = resolve("phi_matvec", backend)
@@ -134,6 +187,7 @@ def phi_matvec(vals, cols, u, *, backend: str | None = None):
     return ops.spmv_pallas(vals, cols, u, interpret=_interpret(backend))
 
 
+@scoped(SCOPES["phi_t_matvec"])
 def phi_t_matvec(vals, cols, v, n_nodes: int, *, backend: str | None = None):
     """u = Φᵀ v (scatter-add)."""
     backend = resolve("phi_t_matvec", backend)
@@ -144,6 +198,7 @@ def phi_t_matvec(vals, cols, v, n_nodes: int, *, backend: str | None = None):
     return ops.spmv_t_pallas(vals, cols, v, n_nodes, interpret=_interpret(backend))
 
 
+@scoped(SCOPES["khat_matvec"])
 def khat_matvec(
     vals_rows, cols_rows, vals_cols, cols_cols, v, n_nodes: int,
     *, backend: str | None = None,
@@ -158,14 +213,16 @@ def khat_matvec(
     from .ell_spmv import ops
 
     if backend == "xla":
-        u = ops.spmv_t_xla(vals_cols, cols_cols, v, n_nodes)
-        return ops.spmv_xla(vals_rows, cols_rows, u)
+        # Through the dispatched halves, so their scopes nest under this one.
+        u = phi_t_matvec(vals_cols, cols_cols, v, n_nodes, backend="xla")
+        return phi_matvec(vals_rows, cols_rows, u, backend="xla")
     return ops.khat_pallas(
         vals_rows, cols_rows, vals_cols, cols_cols, v, n_nodes,
         interpret=_interpret(backend),
     )
 
 
+@scoped(SCOPES["gram_block"])
 def gram_block(
     vals_rows, cols_rows, vals_cols, cols_cols, *, backend: str | None = None,
 ):
@@ -187,6 +244,7 @@ def gram_block(
     )
 
 
+@scoped(SCOPES["woodbury_apply"])
 def woodbury_apply(b, dinv, einv, v, *, backend: str | None = None):
     """M⁻¹v = D⁻¹v − D⁻¹B E⁻¹ BᵀD⁻¹v — the Nyström–Woodbury apply, fused
     on Pallas backends.
@@ -203,6 +261,7 @@ def woodbury_apply(b, dinv, einv, v, *, backend: str | None = None):
     return ops.woodbury_pallas(b, dinv, einv, v, interpret=_interpret(backend))
 
 
+@scoped(SCOPES["walk_sample"])
 def walk_sample(
     neighbors, weights, deg, nodes, seed,
     *, n_walkers: int, p_halt: float, l_max: int, reweight: bool = True,
@@ -230,7 +289,6 @@ def walk_sample(
         n=int(nodes.shape[0]) * int(n_walkers),
         labels=_labels,
     )
-    _obs_taps.count("walks.sample_calls", labels=_labels)
     if backend == "xla":
         return ops.walk_sample_xla(
             neighbors, weights, deg, nodes, seed,

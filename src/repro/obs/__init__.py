@@ -10,7 +10,9 @@ Quickstart::
     print(obs.summary())               # p50/p95/p99 per span, counter totals
 
 Disabled (the default) pays zero overhead: taps are statically compiled
-out, spans are one predicate check.  Jitted consumers thread
+out, spans only enter their ``repro.<name>`` profiler annotation, and the
+compile counter (:func:`compiles`, always on) runs only when something
+compiles.  Jitted consumers thread
 ``obs_tap=obs.enabled()`` as a static argument and pin their trace with
 :func:`tap_scope`, so enablement rides jit cache keys exactly like
 ``spmv_backend``."""
@@ -35,6 +37,7 @@ from .registry import (
     reset_enabled,
     tap_scope,
 )
+from .compile_count import compiles, install as _install_compile_count
 from .report import summary, validate
 from .spans import Span, span
 from .taps import count, tap, tap_dict
@@ -48,6 +51,7 @@ __all__ = [
     "Registry",
     "RingBufferSink",
     "Span",
+    "compiles",
     "count",
     "disable",
     "emit_event",
@@ -67,3 +71,5 @@ __all__ = [
     "tap_scope",
     "validate",
 ]
+
+_install_compile_count()

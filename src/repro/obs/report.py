@@ -26,8 +26,9 @@ from . import registry
 # round-trip test) enforce.  Every event additionally carries (t, seq).
 EVENT_SCHEMA = {
     "meta": ("jax_version", "host_backend", "spmv_backend"),
-    "span": ("name", "path", "depth", "dur_s", "blocked"),
+    "span": ("name", "path", "depth", "start_ns", "dur_s", "blocked"),
     "tap": ("name", "values"),
+    "jit.compile": ("fun_name", "dur_s", "end_ns"),
     "fit_step": ("step", "loss", "cg_iters", "cg_converged"),
     "summary": ("metrics",),
 }

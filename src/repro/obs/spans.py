@@ -6,25 +6,30 @@
         sp.block_on(out)          # honest device timing: wait before stop
         sp.note(fill=0.75)        # extra attrs into the span event
 
-Spans are **host-side**: they time dispatch + (when blocked) device
-execution with ``time.perf_counter``, so they only make sense *outside*
-jit-compiled code — inside a trace, wall time is meaningless and the right
-tool is a tap (obs/taps.py) or the emitted ``jax.profiler``
-annotation.  Every span also enters a ``jax.profiler.TraceAnnotation``
-(a no-op unless a profiler session is active), so span names line up
-with the TensorBoard/perfetto timeline when one is captured.
+Spans are **host-side**: they only make sense *outside* jit-compiled code
+— inside a trace, wall time is meaningless and the right tool is a tap
+(obs/taps.py).  Two switches govern a span:
+
+  * **always** it enters a ``jax.profiler.TraceAnnotation`` named
+    ``repro.<name>`` (a near no-op unless a profiler session is active),
+    so every profile of the program names its host regions;
+  * **only when observability is enabled** does it also time the region
+    with ``time.perf_counter``, block on its ``block=`` target and record
+    the duration (the ``span.<name>`` histogram and a ``span`` event).
 
 JAX dispatch is async: without blocking, a span measures enqueue time, not
 compute.  ``block=`` / :meth:`Span.block_on` make the span
 ``jax.block_until_ready`` the given pytree *inside* the timed window —
 the explicit opt-in for honest device timing (blocking in the hot path is
-a real synchronisation cost, so it is never implicit).
+a real synchronisation cost, so it is never implicit, and never happens
+with observability disabled).
 
 Nesting is tracked with a contextvar stack: each span event records its
-``path`` (slash-joined ancestry) and ``depth``, and the duration lands in
-the ``span.<name>`` histogram of the registry.  When observability is
-disabled, :func:`span` yields a shared no-op object — one predicate check,
-nothing recorded, no annotation entered."""
+``path`` (slash-joined ancestry), ``depth`` and ``start_ns``, its start on
+the clock the profiler stamps host events with (``time.time_ns``; an
+``.xplane.pb`` stores its times relative to the ``profile_start_time``
+stat of its ``Task Environment`` plane), so a flight record and a profile
+of one run line up."""
 from __future__ import annotations
 
 import contextlib
@@ -79,43 +84,48 @@ _NULL = _NullSpan()
 
 @contextlib.contextmanager
 def span(name: str, *, block=None, **attrs):
-    """Time a host-side region as a nested span named ``name``.
+    """Annotate a host-side region as ``repro.<name>``; when observability
+    is enabled, also time it as a nested span named ``name``.
 
     ``block`` (or :meth:`Span.block_on` inside the region) opts into
     device-honest timing; ``attrs`` seed the span event's attributes.
-    Zero work when observability is disabled.  Also a no-op under an active
-    jax trace: span wall-clock is host time, which is meaningless while
-    tracing (an instrumented eager driver called from inside someone else's
-    jit must not record trace time as a span)."""
-    if not registry.enabled() or not trace_state_clean():
+    A no-op under an active jax trace: span wall-clock is host time, which
+    is meaningless while tracing (instrumented eager code called from
+    inside someone else's jit must not record trace time as a span)."""
+    if not trace_state_clean():
         yield _NULL
         return
-    parent = _stack.get()
-    path = "/".join((*parent, name))
-    token = _stack.set((*parent, name))
-    sp = Span(name, path, depth=len(parent))
-    if attrs:
-        sp.note(**attrs)
-    if block is not None:
-        sp.block_on(block)
-    t0 = time.perf_counter()
-    try:
-        with jax.profiler.TraceAnnotation(name):
+    with jax.profiler.TraceAnnotation(f"repro.{name}"):
+        if not registry.enabled():
+            yield _NULL
+            return
+        parent = _stack.get()
+        path = "/".join((*parent, name))
+        token = _stack.set((*parent, name))
+        sp = Span(name, path, depth=len(parent))
+        if attrs:
+            sp.note(**attrs)
+        if block is not None:
+            sp.block_on(block)
+        start_ns = time.time_ns()
+        t0 = time.perf_counter()
+        try:
             yield sp
             if sp._block is not None:
                 jax.block_until_ready(sp._block)
-    finally:
-        dur = time.perf_counter() - t0
-        _stack.reset(token)
-        registry.REGISTRY.observe(f"span.{name}", dur)
-        event = {
-            "type": "span",
-            "name": name,
-            "path": path,
-            "depth": sp.depth,
-            "dur_s": dur,
-            "blocked": sp._block is not None,
-        }
-        if sp.attrs:
-            event["attrs"] = sp.attrs
-        registry.REGISTRY.emit(event)
+        finally:
+            dur = time.perf_counter() - t0
+            _stack.reset(token)
+            registry.REGISTRY.observe(f"span.{name}", dur)
+            event = {
+                "type": "span",
+                "name": name,
+                "path": path,
+                "depth": sp.depth,
+                "start_ns": start_ns,
+                "dur_s": dur,
+                "blocked": sp._block is not None,
+            }
+            if sp.attrs:
+                event["attrs"] = sp.attrs
+            registry.REGISTRY.emit(event)

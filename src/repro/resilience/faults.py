@@ -222,17 +222,10 @@ def corrupt_loads(loads, nodes):
     plan = active()
     if plan is None or not plan.corrupts_payload:
         return loads
-    from .. import obs
-
     u = _hash01(nodes, plan.seed)
     bad_nan = u < plan.nan_payload
     bad_inf = (u >= plan.nan_payload) & (
         u < plan.nan_payload + plan.inf_payload
-    )
-    obs.taps.tap(
-        "faults.nan_payload.injected",
-        jnp.sum(bad_nan | bad_inf).astype(jnp.int32),
-        kind="counter",
     )
     loads = jnp.where(bad_nan[:, None], jnp.float32(jnp.nan), loads)
     return jnp.where(bad_inf[:, None], jnp.float32(jnp.inf), loads)
@@ -245,12 +238,7 @@ def corrupt_schur(d2, node):
     plan = active()
     if plan is None or not plan.corrupts_schur:
         return d2
-    from .. import obs
-
     bad = _hash01(jnp.atleast_1d(node), plan.seed + 1)[0] < plan.chol_fail
-    obs.taps.tap(
-        "faults.chol_fail.injected", bad.astype(jnp.int32), kind="counter"
-    )
     return jnp.where(bad, jnp.float32(-1e-6), d2)
 
 

@@ -149,7 +149,6 @@ class GPServeLoop:
             mean, var, draw = (
                 np.asarray(mean), np.asarray(var), np.asarray(draw)
             )
-        obs.inc("serving.queries_served", len(live))
         obs.observe("serving.wave.fill", fill)
         for i in live:
             req, pos = self.slots[i]
@@ -226,11 +225,6 @@ def _joint_draw_tail(trace_q, vals_q, mean, v, key, n_samples):
     # an all-NaN sample batch.  The joint structure degrades; the BO
     # loop keeps moving.
     ok = jnp.all(jnp.isfinite(l_post))
-    obs.tap(
-        "serving.thompson.cov_fallback",
-        (~ok).astype(jnp.int32),
-        kind="counter",
-    )
     marginal = jnp.diag(jnp.sqrt(jnp.maximum(jnp.diagonal(cov), 0.0)))
     l_post = jnp.where(ok, l_post, marginal)
     eps = jax.random.normal(

@@ -168,7 +168,6 @@ class GPFleetLoop:
                 self.state = update.observe_batch_async(
                     self.state, nodes, ys, donate=self.donate
                 )
-        obs.inc("serving.fleet.observes", int(len(nodes)))
 
     def _apply_forget(self, slots: list[int]) -> None:
         if self.journal is not None:
@@ -284,7 +283,6 @@ class GPFleetLoop:
             req.answered += 1
             if req.answered == len(req.nodes):
                 req.done = True
-        obs.inc("serving.queries_served", w.served)
         self.served += w.served
         return w.served
 

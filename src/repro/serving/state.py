@@ -176,8 +176,9 @@ def query_rows(state: ServeState, query_nodes: jax.Array) -> WalkTrace:
 
 def solve_chol(chol: jax.Array, b: jax.Array) -> jax.Array:
     """x = (L Lᵀ)⁻¹ b via two triangular solves (the no-CG serving solve)."""
-    z = solve_triangular(chol, b, lower=True)
-    return solve_triangular(chol.T, z, lower=False)
+    with jax.named_scope(dispatch.CHOL_SOLVE_SCOPE):
+        z = solve_triangular(chol, b, lower=True)
+        return solve_triangular(chol.T, z, lower=False)
 
 
 def posterior_moments(state: ServeState, query_nodes: jax.Array):
@@ -219,7 +220,8 @@ def _mean_whiten(state: ServeState, k_qx: jax.Array):
     paths, so their downstream math is bit-identical once k_qx agrees."""
     # HIGHEST: at default precision a TPU may round f32 operands to bf16.
     mean = jnp.dot(k_qx, state.alpha, precision=jax.lax.Precision.HIGHEST)
-    v = solve_triangular(state.chol, k_qx.T, lower=True)  # [capacity, q]
+    with jax.named_scope(dispatch.CHOL_SOLVE_SCOPE):
+        v = solve_triangular(state.chol, k_qx.T, lower=True)  # [capacity, q]
     return mean, v
 
 

@@ -102,6 +102,7 @@ def _refit_impl(state: ServeState) -> ServeState:
     )
 
 
+@dispatch.scoped(dispatch.CHOL_UPDATE_SCOPE)
 def _append(state: ServeState, node, y_t) -> ServeState:
     """One *guarded* Cholesky row-append at position m = count (O(m²)).
 
@@ -128,7 +129,8 @@ def _append(state: ServeState, node, y_t) -> ServeState:
         vals1, trace1.cols, state.vals(), state.trace.cols
     )[0]                                      # [capacity]; 0 on dead slots
     k_nn = features.khat_diag_exact(trace1, state.f)[0]
-    ell = solve_triangular(state.chol, k_vec, lower=True)
+    with jax.named_scope(dispatch.CHOL_SOLVE_SCOPE):
+        ell = solve_triangular(state.chol, k_vec, lower=True)
     d2 = k_nn + state.sigma_n2 - jnp.dot(ell, ell)
     d2 = faults.corrupt_schur(d2, node)       # injection site (off: no-op)
     finite = (
@@ -202,11 +204,6 @@ def _observe_batch_impl(graph, f, sigma_n2, seed, packed, nodes, ys, *, cfg,
         )
         (nodes_b, y_b, count, tr, chol, ov, rej, nrf), _ = jax.lax.scan(
             step, init, (nodes, ys)
-        )
-        obs.tap(
-            "serving.observe.overflow",
-            (ov - state.overflow).astype(jnp.int32),
-            kind="counter",
         )
         return (nodes_b, y_b, count, WalkTrace(*tr), chol,
                 solve_chol(chol, y_b), ov, rej, nrf)
@@ -292,7 +289,6 @@ def observe_batch(
             obs_tap=obs.enabled(), fault_plan=faults.active(),
         )
         sp.block_on(packed)
-    obs.inc("serving.observations", int(nodes.shape[0]))
     new = _unpack(state, packed)
     if eager:
         dropped = int(new.overflow) - int(state.overflow)
@@ -342,7 +338,6 @@ def observe_batch_async(state: ServeState, nodes, ys, *,
         nodes, ys, cfg=state.cfg, spmv_backend=dispatch.get_backend(),
         obs_tap=obs.enabled(), fault_plan=faults.active(),
     )
-    obs.inc("serving.observations", int(nodes.shape[0]))
     return _unpack(state, packed)
 
 
@@ -369,6 +364,7 @@ def _cholupdate(chol: jax.Array, x: jax.Array) -> jax.Array:
     return chol
 
 
+@dispatch.scoped(dispatch.CHOL_UPDATE_SCOPE)
 def _forget_step(packed, slot):
     """One downdate on the packed mutable leaves, α left stale.
 
@@ -515,7 +511,6 @@ def ingest(state: ServeState, nodes, ys) -> ServeState:
             obs_tap=obs.enabled(),
         )
         sp.block_on(packed)
-    obs.inc("serving.observations", count)
     return _unpack(state, packed)
 
 

@@ -15,7 +15,9 @@ Contract under test (ISSUE 8 acceptance):
     and count *executions*, not compilations;
   * the ``solver.cg`` tap mirrors the returned CGResult fields.
 """
+import contextlib
 import json
+import re
 import time
 
 import jax
@@ -48,6 +50,12 @@ def ring_sink():
     obs.REGISTRY.add_sink(sink)
     yield sink
     obs.REGISTRY.remove_sink(sink)
+
+
+def _spans(sink):
+    """The span events of a sink (an enabled sink also receives a
+    ``jit.compile`` event per compile)."""
+    return [e for e in sink.events if e["type"] == "span"]
 
 
 # ---------------------------------------------------------------------------
@@ -185,7 +193,7 @@ def test_span_block_on_records_blocked_flag(ring_sink):
     with obs.span("blocked") as sp:
         out = jnp.ones(8) * 2.0
         sp.block_on(out)
-    (ev,) = ring_sink.events
+    (ev,) = _spans(ring_sink)
     assert ev["blocked"] is True
 
 
@@ -206,7 +214,7 @@ def test_span_noop_under_active_trace(ring_sink):
             return x * 2
 
     np.testing.assert_allclose(f(jnp.ones(4)), 2.0)
-    assert not ring_sink.events
+    assert not _spans(ring_sink)
     assert "span.traced" not in obs.REGISTRY.snapshot()["histograms"]
 
 
@@ -426,3 +434,165 @@ def test_fit_step_events_recorded(tmp_path):
         assert np.isfinite(ev["loss"])
         assert ev["cg_iters"] >= 1
         assert isinstance(ev["cg_converged"], bool)
+
+
+# ---------------------------------------------------------------------------
+# The two switches: annotations, scopes and the compile counter always;
+# recording, blocking and taps only with observability enabled.
+# ---------------------------------------------------------------------------
+
+
+class _Recorder:
+    """Stands in for jax.profiler.TraceAnnotation and block_until_ready."""
+
+    def __init__(self):
+        self.annotations, self.blocked = [], []
+
+    def annotation(self, name):
+        self.annotations.append(name)
+        return contextlib.nullcontext()
+
+    def block(self, value):
+        self.blocked.append(value)
+        return value
+
+
+@pytest.fixture()
+def recorder(monkeypatch):
+    rec = _Recorder()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", rec.annotation)
+    monkeypatch.setattr(jax, "block_until_ready", rec.block)
+    return rec
+
+
+@pytest.mark.parametrize("on", [False, True], ids=["obs_off", "obs_on"])
+def test_span_annotates_always_and_records_only_when_enabled(
+        ring_sink, recorder, on):
+    if on:
+        obs.enable()
+    with obs.span("outer"):
+        with obs.span("inner", block=jnp.ones(2)):
+            pass
+    assert recorder.annotations == ["repro.outer", "repro.inner"]
+    assert len(recorder.blocked) == int(on)
+    assert [e["name"] for e in _spans(ring_sink)] == (
+        ["inner", "outer"] if on else [])
+    hists = obs.REGISTRY.snapshot()["histograms"]
+    assert ("span.inner" in hists) == on
+
+
+def test_span_start_lines_up_with_the_profile(ring_sink, tmp_path):
+    """A span event's ``start_ns`` and the span's annotation in the
+    profile agree once the profile's times, which count from its
+    ``profile_start_time``, are shifted by it."""
+    import glob
+
+    obs.enable()
+    with jax.profiler.trace(str(tmp_path)):
+        time.sleep(0.01)
+        with obs.span("aligned"):
+            time.sleep(0.02)
+    (ev,) = _spans(ring_sink)
+    (xplane,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    prof = jax.profiler.ProfileData.from_file(xplane)
+    start = dict(prof.find_plane_with_name("Task Environment").stats)[
+        "profile_start_time"]
+    (ann,) = [e for pl in prof.planes if pl.name.startswith("/host:")
+              for ln in pl.lines for e in ln.events
+              if e.name == "repro.aligned"]
+    assert ann.duration_ns >= 0.02e9
+    assert abs(start + ann.start_ns - ev["start_ns"]) < 5e6   # within 5 ms
+
+
+def test_compile_counter_counts_compiles_not_calls():
+    """Always on: a fresh jit compiles once under its function's name and
+    a cached call compiles nothing, whatever the switch says."""
+    def fresh_fn_for_the_counter(x):
+        return x * 3.0 + 1.0
+
+    f = jax.jit(fresh_fn_for_the_counter)
+    x = jnp.arange(5.0)
+    before = obs.compiles()
+    jax.block_until_ready(f(x))
+    mid = obs.compiles()
+    jax.block_until_ready(f(x))
+    after = obs.compiles()
+    (name,) = [k for k in mid["by_function"]
+               if "fresh_fn_for_the_counter" in k]
+    assert name not in before["by_function"]
+    assert mid["by_function"][name]["count"] == 1
+    assert mid["by_function"][name]["seconds"] > 0
+    assert mid["count"] == before["count"] + 1
+    assert after["count"] == mid["count"]
+    end_ns, _, fun = mid["recent"][-1]
+    assert fun == name and end_ns <= time.time_ns()
+
+
+def test_compile_event_recorded_when_enabled(tmp_path):
+    path = str(tmp_path / "run.jsonl")
+    with obs.recording(path):
+        jax.block_until_ready(jax.jit(lambda v: v - 7.0)(jnp.ones(3)))
+    assert report.validate(path) == []
+    compiles = [e for e in report.read_events(path)
+                if e["type"] == "jit.compile"]
+    assert compiles and all(e["dur_s"] > 0 for e in compiles)
+
+
+def _op_names(lowered) -> str:
+    return "\n".join(re.findall(r'op_name="([^"]*)"',
+                                lowered.compile().as_text()))
+
+
+def _fit_chunk_lowered():
+    from repro.gp import mll
+    from repro.optim.adamw import AdamW
+
+    g = generators.ring(128, k=2)
+    tr = walks.sample_walks_for_nodes(g, jnp.arange(16), jax.random.PRNGKey(0),
+                                      4, 0.3, 3, True)
+    mod = modulation.diffusion(l_max=3)
+    params = mll.init_hyperparams(mod, jax.random.PRNGKey(1), 0.1)
+    opt = AdamW(lr=0.05)
+    return mll._fit_chunk.lower(
+        params, opt.init(params), jax.random.PRNGKey(2), tr, jnp.ones(16),
+        jnp.ones(16), jnp.zeros((16, 3)), mod=mod, opt=opt, n_nodes=128,
+        n_probes=2, strategy=solvers.MLL_DEFAULT, chunk=2,
+        spmv_backend="xla", obs_tap=obs.enabled())
+
+
+def _pathwise_chunked_lowered():
+    from repro.gp import posterior
+
+    g = generators.ring(128, k=2)
+    cfg = walks.WalkConfig(n_walkers=4, p_halt=0.3, l_max=3)
+    mod = modulation.diffusion(l_max=3)
+    f = mod(mod.init(None))
+    return posterior._pathwise_samples_chunked.lower(
+        g, jnp.arange(8), f, 0.1, jnp.ones(8), jax.random.PRNGKey(0),
+        jax.random.PRNGKey(1), jnp.ones(8), cfg=cfg, chunk=32, n_samples=1,
+        strategy=solvers.POSTERIOR_DEFAULT, spmv_backend="xla",
+        obs_tap=obs.enabled())
+
+
+@pytest.mark.parametrize("program,want", [
+    (_fit_chunk_lowered,
+     [r"cg_solve\)?/while/body/grf_khat/grf_phi_t/", "grf_payload"]),
+    (_pathwise_chunked_lowered,
+     ["grf_walks", "/grf_phi/", "grf_phi_t", "cg_solve/while/body/grf_khat"]),
+], ids=["fit_chunk", "pathwise_chunked"])
+def test_programs_carry_product_scopes_and_no_callback(program, want):
+    lowered = program()
+    names = _op_names(lowered)
+    for pat in want:
+        assert re.search(pat, names), pat
+    assert "callback" not in lowered.as_text()
+
+
+def test_cache_key_carries_the_scope_names():
+    """The persistent compilation cache strips op metadata from its key; the
+    scope names go in through jax's key hook, so an executable compiled
+    under other names is never served in place of this program's."""
+    from jax._src import cache_key
+
+    key = cache_key.custom_hook()
+    assert all(name in key for name in dispatch.NAMED_SCOPES)
