@@ -11,8 +11,8 @@ CI bench-regression job diffs against.  Three measurements per size:
                          ``MONO_LIMIT`` where the O(N·K) materialisation is
                          the memory wall the chunked path exists to avoid;
   * ``bo_step``          an end-to-end BO posterior draw at that scale:
-                         pathwise_samples_chunked (prior Φw + CG on the
-                         observation set + chunked K̂_{·x} correction).
+                         pathwise_samples_chunked (CG on the observation
+                         set, then one chunked pass Φ(w + Φ_xᵀα)).
 
 The JSON also records the analytic peak trace bytes for both paths so the
 memory claim is auditable, not just the wall-clock.

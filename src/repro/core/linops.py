@@ -121,9 +121,11 @@ class ChunkedPhiOperator:
     ``row_start``/``n_rows`` select a row range of the full Φ (may be traced
     — the distributed path passes per-shard offsets under shard_map).
     Re-sampling trades compute for memory: every matvec redoes the walk
-    simulation, which is O(N·n_walkers·l_max) gathers — cheap next to the
-    CG chain it feeds, and the hot loops (training-set solves) run on small
-    materialised traces anyway.
+    simulation, O(N·n_walkers·l_max) neighbour gathers, and on a v5e that
+    sampling is most of a product's time (≈ 70% of a 10⁶-node pass; the
+    gather at the walk slots most of the rest).  So callers stream Φ as few
+    times as they can: the pathwise draw makes one pass, Φ(w + Φ_xᵀα), and
+    the training-set solves run on small materialised traces.
     """
 
     graph: Graph

@@ -3,6 +3,8 @@
 A posterior sample over *all* N nodes is a prior sample plus a sparse
 correction:  g|y = g + K̂_{·x}(K̂_xx + σ²I)⁻¹(y − g(x) − ε),
 with the prior sampled as g = Φ w, w ~ N(0, I_N)  (Cov = ΦΦᵀ = K̂).
+Both terms share Φ, so a draw computes it as Φ(w + Φ_xᵀα) with one product
+with the full Φ; g(x) = Φ_x w needs only the training rows.
 Every product is an O(N) sparse op; the solve is CG (Lemma 1) routed
 through the strategy layer (repro.solvers, DESIGN.md §3.8) — pass
 ``strategy=SolveStrategy(preconditioner="nystrom")`` to precondition the
@@ -162,22 +164,36 @@ def _pathwise_samples_impl(
     trace, train_nodes, f, sigma_n2, y, key, n_samples, obs_mask, strategy
 ):
     n = trace.n_nodes
-    t = train_nodes.shape[0]
+    trace_x = features.take_rows(trace, train_nodes)
+    sol, u = _pathwise_correction(
+        trace_x, f, sigma_n2, y, key, n, n_samples, obs_mask, strategy
+    )
+    samples = linops.phi(trace, f, n).matvec(u)
+    return samples, sol.iters, jnp.all(sol.converged)
+
+
+def _pathwise_correction(
+    trace_x, f, sigma_n2, y, key, n, n_samples, obs_mask, strategy
+):
+    """Everything of Eq. 12 but the one product with the full Φ.
+
+    Φ is linear, so the prior sample and the correction share it:
+    g + K̂_{·x}α = Φw + ΦΦ_xᵀα = Φ(w + Φ_xᵀα), and the prior's training rows
+    are g_x = Φ_x w.  Returns the solve and u = w + Φ_xᵀα ([N, n_samples]);
+    the caller streams Φ over N once, as Φu."""
+    t = trace_x.cols.shape[0]
     noise = sigma_n2 if obs_mask is None else jnp.where(obs_mask > 0, sigma_n2, 1e6)
     k_w, k_eps = jax.random.split(key)
     w = jax.random.normal(k_w, (n, n_samples), dtype=jnp.float32)
-    g = linops.phi(trace, f, n).matvec(w)                      # prior sample
-    g_x = g[train_nodes]
+    g_x = features.phi_matvec(trace_x, f, w)                   # prior at x
     eps = jnp.sqrt(sigma_n2) * jax.random.normal(k_eps, (t, n_samples))
     resid = y[:, None] - (g_x + eps)
     if obs_mask is not None:
         resid = resid * obs_mask[:, None]
 
-    trace_x = features.take_rows(trace, train_nodes)
     h = make_h_operator(trace_x, f, noise, n)
     sol = solvers.solve(h, resid, strategy)
-    samples = g + linops.khat_cross(trace, trace_x, f, n).matvec(sol.x)
-    return samples, sol.iters, jnp.all(sol.converged)
+    return sol, w + features.phi_t_matvec(trace_x, f, sol.x, n)
 
 
 def pathwise_samples_chunked(
@@ -200,12 +216,14 @@ def pathwise_samples_chunked(
 ):
     """Eq. 12 over all N nodes with the full-graph Φ *never materialised*.
 
-    The prior draw g = Φw and the cross correction K̂_{·x}u stream Φ in
-    ``chunk``-row blocks (core/linops.ChunkedPhiOperator); only the
-    training-node trace Φ_x is materialised ([T, K]).  Because the walker
-    RNG is counter-based, ``walk_key`` makes Φ_x and the streamed Φ rows of
-    the same underlying feature matrix — this path equals
-    ``pathwise_samples`` on the monolithic trace sampled with ``walk_key``.
+    The draw streams Φ once, in ``chunk``-row blocks
+    (core/linops.ChunkedPhiOperator), as Φ(w + Φ_xᵀα): the prior sample Φw
+    and the correction K̂_{·x}α = ΦΦ_xᵀα in one pass over N.  Only the
+    training-node trace Φ_x is materialised ([T, K]); it gives the prior's
+    training rows Φ_x w and the scatter Φ_xᵀα.  Because the walker RNG is
+    counter-based, ``walk_key`` makes Φ_x and the streamed Φ rows of the
+    same underlying feature matrix — this path equals ``pathwise_samples``
+    on the monolithic trace sampled with ``walk_key``.
     Peak memory: O(chunk·K + N·n_samples) instead of O(N·K).
 
     The training-block solve is a strategy solve on the *materialised*
@@ -252,31 +270,16 @@ def _pathwise_samples_chunked(
     *, cfg, chunk, n_samples, strategy, spmv_backend, obs_tap=False,
 ):
     with obs.tap_scope(obs_tap), dispatch.use_backend(spmv_backend):
-        n = graph.n_nodes
-        t = train_nodes.shape[0]
-        noise = (
-            sigma_n2 if obs_mask is None
-            else jnp.where(obs_mask > 0, sigma_n2, 1e6)
-        )
-        k_w, k_eps = jax.random.split(key)
-        w = jax.random.normal(k_w, (n, n_samples), dtype=jnp.float32)
-        phi_full = linops.chunked_phi(graph, f, walk_key, cfg, chunk)
-        g = phi_full.matvec(w)                                 # prior sample
-        g_x = g[train_nodes]
-        eps = jnp.sqrt(sigma_n2) * jax.random.normal(k_eps, (t, n_samples))
-        resid = y[:, None] - (g_x + eps)
-        if obs_mask is not None:
-            resid = resid * obs_mask[:, None]
-
         trace_x = walks.sample_walks_for_nodes(
             graph, train_nodes, walk_key,
             cfg.n_walkers, cfg.p_halt, cfg.l_max, cfg.reweight, cfg.scheme,
         )
-        h = make_h_operator(trace_x, f, noise, n)
-        sol = solvers.solve(h, resid, strategy)
-        cross = linops.chunked_khat_cross(graph, trace_x, f, walk_key, cfg,
-                                          chunk)
-        return g + cross.matvec(sol.x), sol.iters, jnp.all(sol.converged)
+        sol, u = _pathwise_correction(
+            trace_x, f, sigma_n2, y, key, graph.n_nodes, n_samples, obs_mask,
+            strategy,
+        )
+        samples = linops.chunked_phi(graph, f, walk_key, cfg, chunk).matvec(u)
+        return samples, sol.iters, jnp.all(sol.converged)
 
 
 def predictive_moments_from_samples(samples: jax.Array):

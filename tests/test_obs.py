@@ -299,6 +299,27 @@ def test_tap_under_jit_both_backends(backend):
     np.testing.assert_array_equal(np.asarray(t_off.loads), np.asarray(t_on.loads))
 
 
+def test_pathwise_chunked_streams_phi_once():
+    """A chunked pathwise draw samples every row of Φ once, plus the T
+    training rows: one streamed pass over N, not a prior pass and a
+    correction pass."""
+    from repro.gp import posterior
+
+    n, chunk, t = 128, 32, 8
+    g = generators.ring(n, k=2)
+    cfg = walks.WalkConfig(n_walkers=4, p_halt=0.3, l_max=3)
+    mod = modulation.diffusion(l_max=3)
+    f = mod(mod.init(None))
+    obs.enable()
+    out = posterior.pathwise_samples_chunked(
+        g, jnp.arange(t), f, 0.1, jnp.ones(t), jax.random.PRNGKey(0),
+        jax.random.PRNGKey(1), cfg, chunk=chunk, n_samples=2)
+    jax.block_until_ready(out)
+    rows = obs.REGISTRY.snapshot()["counters"][
+        "walks.rows_sampled{backend=xla,scheme=iid}"]
+    assert rows == -(-n // chunk) * chunk + t
+
+
 def test_count_counts_executions_not_compilations():
     obs.enable()
 

@@ -48,6 +48,37 @@ def test_pathwise_moments_match_exact(problem):
     assert 0.7 < ratio < 1.3, ratio
 
 
+def test_pathwise_one_pass_equals_two_pass_formula(problem):
+    """The draw computes Φ(w + Φ_xᵀα); the textbook order is a prior pass
+    Φw plus a correction pass K̂_{·x}α.  Same keys, same solve: only the
+    association of the sum differs."""
+    from repro import solvers
+    from repro.gp.mll import make_h_operator
+
+    g, tr, f, train, y, s2, *_ = problem
+    n, t, s = g.n_nodes, train.shape[0], 4
+    key = jax.random.PRNGKey(11)
+    strategy = solvers.POSTERIOR_DEFAULT.with_overrides(tol=1e-6,
+                                                        max_iters=600)
+
+    @jax.jit
+    def two_pass(f, y, key):
+        k_w, k_eps = jax.random.split(key)
+        w = jax.random.normal(k_w, (n, s), dtype=jnp.float32)
+        prior = features.phi_matvec(tr, f, w)
+        eps = jnp.sqrt(s2) * jax.random.normal(k_eps, (t, s))
+        resid = y[:, None] - (prior[train] + eps)
+        tr_x = features.take_rows(tr, train)
+        alpha = solvers.solve(make_h_operator(tr_x, f, s2, n), resid,
+                              strategy).x
+        return prior + features.khat_cross_matvec(tr, tr_x, f, alpha, n)
+
+    got = posterior.pathwise_samples(tr, train, f, s2, y, key, n_samples=s,
+                                     strategy=strategy)
+    np.testing.assert_allclose(np.array(got), np.array(two_pass(f, y, key)),
+                               rtol=1e-5, atol=1e-6)
+
+
 def test_nlpd_and_rmse_shapes(problem):
     g, tr, f, train, y, s2, mean_exact, var_exact = problem
     nlpd = posterior.gaussian_nlpd(y, mean_exact[train], var_exact[train] + s2)

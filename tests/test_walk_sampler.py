@@ -145,7 +145,10 @@ def test_chunked_khat_agrees_through_operator_layer(grid100):
                                rtol=1e-4, atol=1e-4)
 
 
-def test_chunked_pathwise_equals_monolithic(grid100):
+@pytest.mark.parametrize("padded", [False, True], ids=["unmasked", "padded"])
+def test_chunked_pathwise_equals_monolithic(grid100, padded):
+    """Both draws stream Φ once as Φ(w + Φ_xᵀα); with ``padded`` the last
+    observation slots are dead (mask 0), as in the BO loop's buffer."""
     from repro.gp import posterior
 
     cfg = walks.WalkConfig(n_walkers=8, p_halt=0.2, l_max=4)
@@ -157,9 +160,12 @@ def test_chunked_pathwise_equals_monolithic(grid100):
     y = jnp.asarray(rng.standard_normal(30), jnp.float32)
     tr = walks.sample_walks(grid100, wkey, cfg.n_walkers, cfg.p_halt,
                             cfg.l_max)
-    mono = posterior.pathwise_samples(tr, train, f, 0.05, y, key, n_samples=3)
+    mask = (jnp.arange(30) < 22).astype(jnp.float32) if padded else None
+    mono = posterior.pathwise_samples(tr, train, f, 0.05, y, key, n_samples=3,
+                                      obs_mask=mask)
     chnk = posterior.pathwise_samples_chunked(grid100, train, f, 0.05, y, key,
-                                              wkey, cfg, chunk=29, n_samples=3)
+                                              wkey, cfg, chunk=29, n_samples=3,
+                                              obs_mask=mask)
     np.testing.assert_allclose(np.array(mono), np.array(chnk),
                                rtol=1e-4, atol=1e-4)
 
