@@ -37,8 +37,9 @@ def build_graph(spec: dict):
     params = {k: v for k, v in spec.items() if k != "generator"}
     graph = jax.block_until_ready(
         getattr(generators, spec["generator"])(**params))
+    deg = np.asarray(graph.deg, np.int64)
     log(f"graph {spec['generator']} {params}: {graph.n_nodes} nodes, "
-        f"max degree {graph.max_deg}")
+        f"max degree {deg.max()}, {deg.sum()} stored entries")
     return graph
 
 

@@ -228,15 +228,15 @@ class Job:
         of the largest reference value there; the worst leaf's gap of the
         hyperparameters' change over each refit of the window; and the
         rounds whose buffer lost the previous pick (exact, limit 0)."""
-        return [("draw_gap", self.draw_gap(), self._limit("draw_gap")),
-                ("refit_gap", self.refit_gap(), self._limit("refit_gap")),
+        adj = reference.Adjacency.from_spec(self.config["graph"])
+        return [("draw_gap", self.draw_gap(adj), self._limit("draw_gap")),
+                ("refit_gap", self.refit_gap(adj), self._limit("refit_gap")),
                 ("loop_gap", float(self.loop_gaps), self._limit("loop_gap"))]
 
-    def refit_gap(self) -> float:
+    def refit_gap(self, adj: reference.Adjacency) -> float:
         import jax
 
         wk = self.config["walks"]
-        adj = reference.Adjacency.from_spec(self.config["graph"])
         worst = 0.0
         for r in self.refits:
             seed_u32 = int(np.asarray(jax.random.bits(
@@ -256,11 +256,10 @@ class Job:
                 r["init"], r["params"], ref["params"], ref["grad0"]))
         return worst
 
-    def draw_gap(self) -> float:
+    def draw_gap(self, adj: reference.Adjacency) -> float:
         import jax
 
         cfg = self.config
-        adj = reference.Adjacency.from_spec(cfg["graph"])
         wk = cfg["walks"]
         n = adj.n_nodes
         rng = data.np_rng(self.seed, 3)

@@ -3,14 +3,17 @@
 Independent of the code under test: it imports nothing of ``repro`` and
 rebuilds what it needs from the configuration and the seed.
 
-  * adjacency: each node's neighbours in ascending id order, walk-matrix
-    entries 1/sqrt(d_i d_j) (the symmetric normalised adjacency);
+  * adjacency: edge-sized (CSR), built from the edge list that
+    ``bench/harness/graphs/<generator>.py`` returns; each node's
+    neighbours in ascending id order, walk-matrix entries 1/sqrt(d_i d_j)
+    (the symmetric normalised adjacency);
   * walks (paper Alg. 2 with fixed-length masked stepping): ``n_walkers``
     walkers per start node take ``l_max`` moves; at step l a walker
     deposits (node, load·alive, l); the move picks neighbour
     floor(u·d) with u from the murmur3-finaliser counter hash keyed on
     (seed, start node, walker, 2l); the load gains d/(1−p_halt)·w; the
     walker halts for good when the uniform keyed on 2l+1 is below p_halt;
+    at a degree-0 node it stays and carries zero load from then on;
     loads are divided by n_walkers at the end;
   * Φ rows as sparse matrices with values loads·f[lens], K̂ = Φ_A Φ_Bᵀ;
   * GP algebra by dense float64 Cholesky.
@@ -22,6 +25,8 @@ from __future__ import annotations
 import numpy as np
 import scipy.linalg
 import scipy.sparse
+
+from harness import spec
 
 _GOLDEN = np.uint32(0x9E3779B9)
 _M1 = np.uint32(0x85EBCA6B)
@@ -52,40 +57,39 @@ def counter_uniform(seed, node, walker, ctr) -> np.ndarray:
 
 
 class Adjacency:
-    """Neighbours (ascending id, padded), degrees and walk-matrix entries."""
+    """Edge-sized (CSR) adjacency: node i's neighbours are
+    ``nbr[offsets[i]:offsets[i+1]]`` in ascending id, with walk-matrix
+    entries ``w`` at the same places and degrees ``deg``."""
 
-    def __init__(self, neighbors: np.ndarray, deg: np.ndarray):
-        self.neighbors = neighbors
-        self.deg = deg
-        d = np.maximum(deg, 1).astype(np.float64)
-        live = np.arange(neighbors.shape[1])[None, :] < deg[:, None]
-        self.weights = np.where(
-            live, 1.0 / np.sqrt(d[:, None] * d[neighbors]), 0.0)
+    def __init__(self, offsets: np.ndarray, nbr: np.ndarray):
+        self.offsets, self.nbr = offsets, nbr
+        self.deg = np.diff(offsets)
+        src = np.repeat(np.arange(len(self.deg)), self.deg)
+        d = self.deg.astype(np.float64)
+        self.w = 1.0 / np.sqrt(d[src] * d[nbr])
 
     @property
     def n_nodes(self) -> int:
-        return self.neighbors.shape[0]
+        return len(self.deg)
 
     @classmethod
-    def from_spec(cls, spec: dict) -> "Adjacency":
-        kind = spec["generator"]
-        if kind == "ring":
-            n, k = spec["n_nodes"], spec.get("k", 1)
-            i = np.arange(n)[:, None]
-            offs = np.concatenate([-np.arange(k, 0, -1), np.arange(1, k + 1)])
-            nbr = np.sort((i + offs[None, :]) % n, axis=1)
-            return cls(nbr, np.full(n, 2 * k, np.int64))
-        if kind == "grid2d":
-            rows, cols = spec["rows"], spec["cols"]
-            idx = np.arange(rows * cols)
-            r, c = idx // cols, idx % cols
-            cand = np.stack([idx - cols, idx - 1, idx + 1, idx + cols], axis=1)
-            ok = np.stack([r > 0, c > 0, c < cols - 1, r < rows - 1], axis=1)
-            # Valid neighbours first, in ascending id order (cand is sorted).
-            order = np.argsort(~ok, axis=1, kind="stable")
-            nbr = np.take_along_axis(np.where(ok, cand, 0), order, axis=1)
-            return cls(nbr, ok.sum(axis=1))
-        raise ValueError(f"no reference adjacency for generator {kind!r}")
+    def from_edges(cls, edges, n_nodes: int) -> "Adjacency":
+        """From an undirected edge list [E, 2]: symmetrised, each directed
+        entry kept once."""
+        e = np.asarray(edges, np.int64).reshape(-1, 2)
+        key = np.unique(np.concatenate([e[:, 0] * n_nodes + e[:, 1],
+                                        e[:, 1] * n_nodes + e[:, 0]]))
+        src, nbr = np.divmod(key, n_nodes)
+        offsets = np.zeros(n_nodes + 1, np.int64)
+        np.cumsum(np.bincount(src, minlength=n_nodes), out=offsets[1:])
+        return cls(offsets, nbr)
+
+    @classmethod
+    def from_spec(cls, graph: dict) -> "Adjacency":
+        """From a configuration's ``graph``, by the ``edges(spec)`` of
+        ``bench/harness/graphs/<generator>.py``."""
+        edges, n_nodes = spec.graph_edges(graph["generator"])(graph)
+        return cls.from_edges(edges, n_nodes)
 
 
 def walks(adj: Adjacency, nodes, seed: int, n_walkers: int, p_halt: float,
@@ -107,11 +111,16 @@ def walks(adj: Adjacency, nodes, seed: int, n_walkers: int, p_halt: float,
         d = adj.deg[cur]
         choice = np.minimum((u * d.astype(np.float32)).astype(np.int64),
                             np.maximum(d - 1, 0))
-        nxt = adj.neighbors[cur, choice]
-        w = adj.weights[cur, choice]
+        # A degree-0 node has no entry to read (the last one's offset is E).
+        live = d > 0
+        at = (adj.offsets[cur] + choice)[live]
+        nxt = cur.copy()
+        nxt[live] = adj.nbr[at]
+        w = np.zeros(cur.shape)
+        w[live] = adj.w[at]
         load = load * d / (1.0 - p_halt) * w
         u_h = counter_uniform(seed, node_u, walker, 2 * step + 1)
-        alive = alive * (u_h >= p32) * (d > 0)
+        alive = alive * (u_h >= p32) * live
         cur = nxt
     k = n_walkers * (l_max + 1)
     cols = np.stack(cols, axis=-1).reshape(m, k)

@@ -6,7 +6,9 @@ in a file of its own, found by the name ``BENCHMARK.json`` gives it:
   * a configuration: the ``file`` of its ``configs`` entry;
   * a traffic mix: ``bench/traffic/<traffic>.json``, whose ``job`` key
     names the kind of job under ``bench/harness/jobs/``;
-  * a metric: ``bench/metrics/<metric name>.py``, with ``read(run)``.
+  * a metric: ``bench/metrics/<metric name>.py``, with ``read(run)``;
+  * a graph's float64 reference: ``bench/harness/graphs/<generator>.py``,
+    with ``edges(spec)``.
 """
 from __future__ import annotations
 
@@ -17,6 +19,7 @@ import os
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
+GRAPHS_DIR = os.path.join(BENCH_DIR, "harness", "graphs")
 
 
 @dataclasses.dataclass
@@ -47,6 +50,14 @@ def resolve(workload: str, root: str | None = None) -> Cell:
     w = cells[workload]
     configs = {c["name"]: c for c in bench["configs"]}
     config = load_json(os.path.join(root, configs[w["config"]]["file"]))
+    # The reference runs only after the window: a graph it cannot build
+    # is refused here, before any set-up.
+    if "graph" in config:
+        path = graph_file(config["graph"]["generator"])
+        if not os.path.isfile(path):
+            raise ValueError(
+                f"configuration {w['config']!r}: no reference edge list for "
+                f"graph generator {config['graph']['generator']!r} ({path})")
     traffic = load_json(os.path.join(root, os.path.basename(BENCH_DIR),
                                      "traffic", f"{w['traffic']}.json"))
     return Cell(
@@ -76,3 +87,13 @@ def job(kind: str):
     """The module of a kind of job (``bench/harness/jobs/<kind>.py``)."""
     path = os.path.join(BENCH_DIR, "harness", "jobs", f"{kind}.py")
     return _load_module(path, "bench_job_" + kind)
+
+
+def graph_file(generator: str) -> str:
+    return os.path.join(GRAPHS_DIR, f"{generator}.py")
+
+
+def graph_edges(generator: str):
+    """The ``edges(spec) -> (int64[E, 2], n_nodes)`` function of
+    ``bench/harness/graphs/<generator>.py``."""
+    return _load_module(graph_file(generator), "bench_graph_" + generator).edges

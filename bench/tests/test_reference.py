@@ -3,6 +3,7 @@ from the configuration alone.
 
     JAX_PLATFORMS=cpu python -m pytest bench/tests -q
 """
+import json
 import os
 import sys
 
@@ -31,10 +32,12 @@ def test_adjacency_matches_the_generator(spec):
     g = _program_graph(spec)
     adj = reference.Adjacency.from_spec(spec)
     assert np.array_equal(np.asarray(g.deg), adj.deg)
-    live = np.arange(adj.neighbors.shape[1])[None, :] < adj.deg[:, None]
-    assert np.array_equal(np.where(live, np.asarray(g.neighbors), 0),
-                          np.where(live, adj.neighbors, 0))
-    assert np.allclose(np.asarray(g.weights), adj.weights, rtol=1e-6)
+    assert adj.offsets[-1] == len(adj.nbr) == len(adj.w)
+    nbr, wgt = np.asarray(g.neighbors), np.asarray(g.weights)
+    for i in range(adj.n_nodes):
+        row = slice(adj.offsets[i], adj.offsets[i + 1])
+        assert np.array_equal(nbr[i, :adj.deg[i]], adj.nbr[row])
+        assert np.allclose(wgt[i, :adj.deg[i]], adj.w[row], rtol=1e-6)
 
 
 @pytest.mark.parametrize("spec", SPECS, ids=lambda s: s["generator"])
@@ -54,6 +57,81 @@ def test_walks_match_the_program(spec):
     assert np.array_equal(np.asarray(tr.cols), cols)
     assert np.array_equal(np.asarray(tr.lens), lens)
     assert np.allclose(np.asarray(tr.loads), loads, rtol=1e-5, atol=1e-7)
+
+
+def _heavy_tailed():
+    """A hub with 40 leaves, a chain of 10 nodes, two isolated nodes (the
+    last id among them), and edges given twice, once reversed."""
+    star = [(0, leaf) for leaf in range(1, 41)]
+    chain = [(i, i + 1) for i in range(41, 50)]
+    dup = [(0, 1), (2, 0), (42, 41)]
+    return np.array(star + chain + dup, np.int64), 53
+
+
+def test_walks_match_the_program_on_a_heavy_tailed_graph():
+    import jax
+    import jax.numpy as jnp
+    from repro.core import walks
+    from repro.graphs import formats
+
+    edges, n = _heavy_tailed()
+    g = formats.from_edges(edges, n)
+    adj = reference.Adjacency.from_edges(edges, n)
+    assert np.array_equal(np.asarray(g.deg), adj.deg)
+    assert adj.deg[0] == 40 and adj.deg[51] == adj.deg[52] == 0
+    key = jax.random.PRNGKey(2**31 + 9)
+    nodes = np.arange(n)
+    tr = walks.sample_walks_for_nodes(g, jnp.asarray(nodes, jnp.int32), key,
+                                      30, 0.15, 5)
+    seed = int(walks.walk_seed(key))
+    ref = reference.walks(adj, nodes, seed, 30, 0.15, 5)
+    cols, loads, lens = ref
+    assert np.array_equal(np.asarray(tr.lens), lens)
+    assert np.allclose(np.asarray(tr.loads), loads, rtol=1e-5, atol=1e-7)
+    # The program parks a walker at a degree-0 node on its padding (node
+    # 0) with zero load; the reference keeps it in place.
+    live = loads != 0
+    assert np.array_equal(np.asarray(tr.cols)[live], cols[live])
+    f = reference.diffusion_f(0.3, -0.2, 5)[0]
+    prog = (np.asarray(tr.cols), np.asarray(tr.loads, np.float64), lens)
+    phi_p = reference.phi(prog, f, n).toarray()
+    phi_r = reference.phi(ref, f, n).toarray()
+    assert np.allclose(phi_p, phi_r, rtol=1e-5, atol=1e-7)
+    for i in (51, 52):      # only the length-0 deposit, 30 × f_0/30
+        assert np.flatnonzero(phi_r[i]).tolist() == [i]
+        assert np.isclose(phi_r[i, i], f[0], rtol=1e-12, atol=0)
+
+
+def test_from_spec_finds_the_graph_file_by_name(tmp_path, monkeypatch):
+    from harness import spec
+
+    (tmp_path / "triangle_tail.py").write_text(
+        "import numpy as np\n\n\n"
+        "def edges(spec):\n"
+        "    return np.array([[0, 1], [1, 2], [2, 0], [2, 3]]), spec['n']\n")
+    monkeypatch.setattr(spec, "GRAPHS_DIR", str(tmp_path))
+    adj = reference.Adjacency.from_spec({"generator": "triangle_tail", "n": 5})
+    assert adj.offsets.tolist() == [0, 2, 4, 7, 8, 8]
+    assert adj.nbr.tolist() == [1, 2, 0, 2, 0, 1, 3, 2]
+    assert adj.deg.tolist() == [2, 2, 3, 1, 0]
+
+
+def test_resolve_refuses_a_graph_without_its_file(tmp_path):
+    from harness import spec
+
+    bench = {"configs": [{"name": "x", "file": "bench/configs/x.json"}],
+             "workloads": [{"name": "x.fit", "config": "x", "traffic": "fit",
+                            "chips": 1}],
+             "end_to_end": [], "per_layer": []}
+    (tmp_path / "bench" / "configs").mkdir(parents=True)
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    (tmp_path / "bench" / "configs" / "x.json").write_text(
+        json.dumps({"graph": {"generator": "no_such_graph", "n": 5}}))
+    with pytest.raises(ValueError) as err:
+        spec.resolve("x.fit", root=str(tmp_path))
+    assert spec.graph_file("no_such_graph") in str(err.value)
+    assert os.path.join("harness", "graphs", "no_such_graph.py") in str(
+        err.value)
 
 
 def test_diffusion_modulation_and_derivatives():
