@@ -2,6 +2,7 @@
 signals.  The program receives only what these build."""
 from __future__ import annotations
 
+import inspect
 import sys
 import time
 
@@ -55,13 +56,18 @@ def modulation(spec: dict, l_max: int):
     return mod.REGISTRY[spec["name"]](l_max=l_max)
 
 
-def signal(spec: dict, seed: int) -> np.ndarray:
+def signal(spec: dict, seed: int, graph=None) -> np.ndarray:
     """Ground truth over all nodes: ``repro.graphs.signals.<name>``, its
     random draws keyed on the configuration's ``seed`` where it fixes the
-    deployment's data, else on the run's ``seed``."""
+    deployment's data, else on the run's ``seed``.  A signal defined on the
+    graph (one with a ``graph`` parameter, such as an influence proxy read
+    from degrees) is handed the graph the job built."""
     from repro.graphs import signals
 
+    fn = getattr(signals, spec["name"])
     params = {k: v for k, v in spec.items()
               if k not in ("name", "noise_std", "seed")}
-    return np.asarray(getattr(signals, spec["name"])(
-        **params, seed=spec.get("seed", seed) % (2**32)), np.float64)
+    if "graph" in inspect.signature(fn).parameters:
+        params["graph"] = graph
+    return np.asarray(fn(**params, seed=spec.get("seed", seed) % (2**32)),
+                      np.float64)

@@ -22,7 +22,7 @@ import time
 
 import numpy as np
 
-from harness import data, reference
+from harness import data, reference, rooflines
 from harness.trace import annotate
 
 
@@ -51,7 +51,8 @@ class Job:
         self.graph = data.build_graph(cfg["graph"])
         self.walk = data.walk_config(cfg["walks"])
         self.mod = data.modulation(cfg["modulation"], self.walk.l_max)
-        truth = data.signal(cfg["objective"], self.seed)
+        self.walk_rows = rooflines.walk_rows(self.graph.deg)
+        truth = data.signal(cfg["objective"], self.seed, self.graph)
         noise_rng = data.np_rng(self.seed, 1)
         noise_std = cfg["objective"]["noise_std"]
 
@@ -175,7 +176,8 @@ class Job:
         self.window_s = time.perf_counter() - t0
         self.recording = False
         self.counts = {"rounds": rounds, "draws": len(self.draws),
-                       "refits": len(self.refits)}
+                       "refits": len(self.refits),
+                       "walk_rows": self.walk_rows}
         self.attempted, self.failed = rounds, 0
 
     def release(self) -> None:

@@ -50,7 +50,7 @@ class Job:
         data_seed = cfg["targets"].get("seed", self.seed)
         rng = data.np_rng(data_seed)
         self.train = (cfg["train_start"] + np.arange(t)) % self.n
-        truth = data.signal(cfg["targets"], self.seed)
+        truth = data.signal(cfg["targets"], self.seed, graph)
         self.y = (truth[self.train] + cfg["targets"]["noise_std"]
                   * rng.standard_normal(t)).astype(np.float32)
         self.walk_key = jax.random.fold_in(

@@ -49,7 +49,7 @@ class Job:
         f = mod(mod.init(jax.random.fold_in(key, 2)))
         self.f = np.asarray(f, np.float64)
         self.s2 = sv["sigma_n2"]
-        self.truth = data.signal(cfg["targets"], self.seed)
+        self.truth = data.signal(cfg["targets"], self.seed, graph)
         self.noise_std = cfg["targets"]["noise_std"]
         self.rng = data.np_rng(self.seed)
         nodes0 = self.rng.choice(self.n, sv["live"], replace=False)

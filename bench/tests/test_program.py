@@ -16,7 +16,7 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 sys.path.insert(0, BENCH)
 
 import run as bench_run  # noqa: E402
-from harness import program, trace  # noqa: E402
+from harness import program, rooflines, trace  # noqa: E402
 
 MS = 1_000_000  # ns
 START_NS = 1_700_000_000_000_000_000    # the profile's start, host clock
@@ -180,3 +180,38 @@ def test_existing_readers_read_as_before(tmp_path, monkeypatch, name):
                                         scoped=False))
     after = metric(name).read(make_run(tmp_path / "new", monkeypatch))
     assert before is not None and after == before
+
+
+def test_walk_roofline_on_the_trace(tmp_path, monkeypatch):
+    """One draw in the window, 12 ms under ``grf_walks``; 1,000 start nodes
+    of degree >= 1, 30 walkers, p_halt 0.15, l_max 5."""
+    run = make_run(tmp_path, monkeypatch)
+    run.config = {"walks": {"n_walkers": 30, "p_halt": 0.15, "l_max": 5}}
+    run.counts = {"walk_rows": 1000}
+    moves = 1000 * 30 * (0.85 + 0.85**2 + 0.85**3 + 0.85**4 + 0.85**5)
+    want = 100.0 * moves * 12 / 819e9 / 0.012
+    assert metric("walk_roofline.bo").read(run) == pytest.approx(want)
+    assert want == pytest.approx(0.011547, rel=1e-4)
+    run.counts = {}
+    assert metric("walk_roofline.bo").read(run) is None
+    run = make_run(tmp_path / "unscoped", monkeypatch, scoped=False)
+    run.config, run.counts = {"walks": {"n_walkers": 30, "p_halt": 0.15,
+                                        "l_max": 5}}, {"walk_rows": 1000}
+    assert metric("walk_roofline.bo").read(run) is None
+
+
+@pytest.mark.parametrize("scheme,moves", [
+    ("iid", 2 * (0.75 + 0.75**2 + 0.75**3)),
+    ("antithetic", 2 * (0.75 + 0.75**2 + 0.75**3)),
+    ("grfspp", 2 * 3)])
+def test_walk_least_bytes(scheme, moves):
+    """12 B per expected move of each of 2 walkers from each of 7 rows,
+    p_halt 0.25, l_max 3; ``grfspp`` walkers never halt."""
+    assert rooflines.walk_least_bytes(7, 2, 0.25, 3, scheme) == \
+        pytest.approx(7 * moves * 12, rel=1e-15)
+
+
+def test_walk_rows_leave_out_degree_zero():
+    assert rooflines.walk_rows([0, 3, 1, 0, 64483]) == 3
+    assert rooflines.walk_least_bytes(
+        rooflines.walk_rows([0, 0]), 30, 0.15, 5) == 0

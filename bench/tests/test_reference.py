@@ -1,5 +1,7 @@
 """The float64 reference rebuilds the program's graph, walks and modulation
-from the configuration alone.
+from the configuration alone: the graph of every configuration in
+``BENCHMARK.json``, at its test size, compared through views that do not
+depend on the layout the program holds it in.
 
     JAX_PLATFORMS=cpu python -m pytest bench/tests -q
 """
@@ -14,46 +16,39 @@ BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(os.path.dirname(BENCH), "src"))
 sys.path.insert(0, BENCH)
 
+import common  # noqa: E402
 from harness import reference  # noqa: E402
 
-SPECS = [{"generator": "ring", "n_nodes": 3000, "k": 2},
-         {"generator": "grid2d", "rows": 23, "cols": 31}]
+CONFIGS = {c["name"]: c for c in common.configs()}
 
 
-def _program_graph(spec):
-    from repro.graphs import generators
-
-    params = {k: v for k, v in spec.items() if k != "generator"}
-    return getattr(generators, spec["generator"])(**params)
-
-
-@pytest.mark.parametrize("spec", SPECS, ids=lambda s: s["generator"])
-def test_adjacency_matches_the_generator(spec):
-    g = _program_graph(spec)
-    adj = reference.Adjacency.from_spec(spec)
-    assert np.array_equal(np.asarray(g.deg), adj.deg)
-    assert adj.offsets[-1] == len(adj.nbr) == len(adj.w)
-    nbr, wgt = np.asarray(g.neighbors), np.asarray(g.weights)
-    for i in range(adj.n_nodes):
-        row = slice(adj.offsets[i], adj.offsets[i + 1])
-        assert np.array_equal(nbr[i, :adj.deg[i]], adj.nbr[row])
-        assert np.allclose(wgt[i, :adj.deg[i]], adj.w[row], rtol=1e-6)
+@pytest.fixture(params=sorted(CONFIGS))
+def config(request):
+    """A configuration of ``BENCHMARK.json`` at its test size."""
+    return common.at_test_size(CONFIGS[request.param])
 
 
-@pytest.mark.parametrize("spec", SPECS, ids=lambda s: s["generator"])
-def test_walks_match_the_program(spec):
+def test_adjacency_matches_the_generator(config):
+    common.check_adjacency(config["graph"])
+
+
+def test_walks_match_the_program(config):
     import jax
     import jax.numpy as jnp
+    from harness import data
     from repro.core import walks
 
-    g = _program_graph(spec)
-    adj = reference.Adjacency.from_spec(spec)
+    g = data.build_graph(config["graph"])
+    adj = reference.Adjacency.from_spec(config["graph"])
+    wk = config["walks"]
     key = jax.random.PRNGKey(2**31 + 5)
     nodes = jnp.arange(0, g.n_nodes, 5, dtype=jnp.int32)
-    tr = walks.sample_walks_for_nodes(g, nodes, key, 30, 0.15, 5)
+    tr = walks.sample_walks_for_nodes(g, nodes, key, wk["n_walkers"],
+                                      wk["p_halt"], wk["l_max"])
     seed = int(walks.walk_seed(key))
-    cols, loads, lens = reference.walks(adj, np.asarray(nodes), seed, 30,
-                                        0.15, 5)
+    cols, loads, lens = reference.walks(adj, np.asarray(nodes), seed,
+                                        wk["n_walkers"], wk["p_halt"],
+                                        wk["l_max"])
     assert np.array_equal(np.asarray(tr.cols), cols)
     assert np.array_equal(np.asarray(tr.lens), lens)
     assert np.allclose(np.asarray(tr.loads), loads, rtol=1e-5, atol=1e-7)

@@ -23,6 +23,7 @@ ROOT = os.path.dirname(BENCH)
 sys.path.insert(0, os.path.join(ROOT, "src"))
 sys.path.insert(0, BENCH)
 
+import common  # noqa: E402
 import run as bench_run  # noqa: E402
 from harness import device, faults, spec  # noqa: E402
 
@@ -30,34 +31,27 @@ BO, FIT = "grid1000_bo.thompson", "ring2p20_regression.fit"
 SERVE = "ring2p20_regression.serve_zipf"
 
 
-def _shrink(root):
-    """A copy of BENCHMARK.json, configurations and traffic mixes under
-    ``root``, each cut to a test's size (widths, walks and mixes kept)."""
-    bench = spec.load_json(os.path.join(ROOT, "BENCHMARK.json"))
-    # The serving cell waits for its rate from a sweep on the chip; its job,
-    # traffic mix and reference are tested here all the same.
-    if SERVE not in {w["name"] for w in bench["workloads"]}:
-        bench["workloads"].append({"name": SERVE,
-                                   "config": "ring2p20_regression",
-                                   "traffic": "serve_zipf", "chips": 1})
+# The serving cell waits for its rate from a sweep on the chip; its job,
+# traffic mix and reference are tested here all the same.
+SERVE_CELL = {"name": SERVE, "config": "ring2p20_regression",
+              "traffic": "serve_zipf", "chips": 1}
+
+
+def _shrink(root, src=ROOT, cells=()):
+    """A copy of ``src``'s BENCHMARK.json (with ``cells`` added where it
+    lacks them), configurations and traffic mixes under ``root``, each
+    configuration cut by its sizes file (``common.at_test_size``) and each
+    mix to a test's length (widths, walks and mixes kept)."""
+    bench = spec.load_json(os.path.join(src, "BENCHMARK.json"))
+    names = {w["name"] for w in bench["workloads"]}
+    bench["workloads"] += [w for w in cells if w["name"] not in names]
     os.makedirs(os.path.join(root, "bench", "configs"))
     os.makedirs(os.path.join(root, "bench", "traffic"))
     for c in bench["configs"]:
-        cfg = spec.load_json(os.path.join(ROOT, c["file"]))
-        g = cfg["graph"]
-        if g["generator"] == "grid2d":
-            g.update(rows=30, cols=40)
-            cfg["objective"].update(rows=30, cols=40)
-            cfg["bo"].update(n_init=20, capacity=60, refit_every=5)
-        else:
-            g["n_nodes"] = cfg["targets"]["n_nodes"] = 4096
-            cfg["n_train"] = 256
-            if "serving" in cfg:
-                cfg["serving"].update(capacity=256, live=224, batch=32)
         with open(os.path.join(root, c["file"]), "w") as fh:
-            json.dump(cfg, fh)
+            json.dump(common.at_test_size(c, src), fh)
     for w in bench["workloads"]:
-        tr = spec.load_json(os.path.join(BENCH, "traffic",
+        tr = spec.load_json(os.path.join(src, "bench", "traffic",
                                          f"{w['traffic']}.json"))
         if "fit" in tr:
             tr["fit"]["steps"] = 20
@@ -75,13 +69,13 @@ def _shrink(root):
 @pytest.fixture(scope="module")
 def root(tmp_path_factory):
     r = str(tmp_path_factory.mktemp("bench_root"))
-    _shrink(r)
+    _shrink(r, cells=[SERVE_CELL])
     return r
 
 
-@pytest.fixture
-def cpu_run(root, monkeypatch, tmp_path):
-    """run.main against the shrunken tree, with the chip check stubbed."""
+def _on_the_cpu(root, monkeypatch, trace_dir):
+    """run.main against the shrunken tree ``root``, with the chip check
+    stubbed: ``go(workload, ...)`` returns the result line."""
     import jax
 
     monkeypatch.setattr(spec, "ROOT", root)
@@ -91,7 +85,7 @@ def cpu_run(root, monkeypatch, tmp_path):
     monkeypatch.setattr(device, "peaks",
                         lambda kind: {"hbm_bytes_per_s": 819e9})
     monkeypatch.setattr(device, "memory_peak_bytes", lambda devs: 0)
-    monkeypatch.setattr(bench_run, "TRACE_DIR", str(tmp_path / "trace"))
+    monkeypatch.setattr(bench_run, "TRACE_DIR", trace_dir)
 
     def go(workload, trace=0, seconds=1.5, seed=2**31 + 3):
         out = io.StringIO()
@@ -103,6 +97,11 @@ def cpu_run(root, monkeypatch, tmp_path):
         return json.loads(out.getvalue().strip().splitlines()[-1])
 
     return go
+
+
+@pytest.fixture
+def cpu_run(root, monkeypatch, tmp_path):
+    return _on_the_cpu(root, monkeypatch, str(tmp_path / "trace"))
 
 
 @pytest.mark.parametrize("workload", [BO, FIT, SERVE])
@@ -169,3 +168,124 @@ def test_control_fails_a_limit(root, monkeypatch, workload):
     assert prog and ctl
     assert all(v[k] <= limits[k] for v in prog for k in limits), prog
     assert any(v[k] > limits[k] for v in ctl for k in v), ctl
+
+
+# -- a configuration of a new graph generator, as new files only --------------
+
+TOY = "toy_bo"
+TOY_CELL = {"name": f"{TOY}.thompson", "config": TOY, "traffic": "thompson",
+            "chips": 1, "why": "a graph with no coordinates and a skewed "
+            "degree law, its objective read from the graph"}
+
+# The reference's edge list: a chain, each node i >= 1 also hanging from
+# node isqrt(i) - 1 (so node j has about 2j children), and the last
+# ``isolated`` ids left without an edge.
+TOY_EDGES = '''"""A chain with a square-root tree over it, some isolated nodes last."""
+import numpy as np
+
+
+def edges(spec):
+    n, live = spec["n_nodes"], spec["n_nodes"] - spec["isolated"]
+    i = np.arange(1, live)
+    parent = np.sqrt(i).astype(np.int64)
+    parent -= parent * parent > i
+    return np.concatenate([np.stack([i - 1, i], 1),
+                           np.stack([parent - 1, i], 1)]), n
+'''
+
+TOY_CONFIG = {
+    "name": TOY,
+    "source": "a test's own configuration",
+    "graph": {"generator": "sqrt_tree", "n_nodes": 1 << 20, "isolated": 64},
+    "walks": {"n_walkers": 30, "p_halt": 0.15, "l_max": 5},
+    "modulation": {"name": "diffusion"},
+    "objective": {"name": "degree_influence", "noise_std": 0.05},
+    "bo": {"n_init": 100, "refit_every": 15, "refit_steps": 8,
+           "capacity": 400, "batch_size": 1},
+    "precision": "float32",
+}
+TOY_SIZES = {"graph": {"n_nodes": 1200},
+             "bo": {"n_init": 20, "capacity": 60, "refit_every": 5}}
+
+
+def sqrt_tree(n_nodes, isolated):
+    """The program's side of the toy generator, built edge by edge."""
+    import math
+
+    from repro.graphs import formats
+
+    edges = []
+    for i in range(1, n_nodes - isolated):
+        edges += [(i - 1, i), (math.isqrt(i) - 1, i)]
+    return formats.from_edges(edges, n_nodes)
+
+
+def degree_influence(graph, seed=0):
+    """An influence proxy read from the graph: log(1 + degree), jittered."""
+    import numpy as np
+
+    deg = np.asarray(graph.deg, np.float64)
+    return np.log1p(deg) + 0.1 * np.random.default_rng(seed).standard_normal(
+        len(deg))
+
+
+def _plant(tree):
+    """A copy of the benchmark's files under ``tree`` with the toy
+    configuration's files added and its cell listed; no file edited."""
+    import shutil
+
+    shutil.copytree(BENCH, os.path.join(tree, "bench"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    bench = spec.load_json(os.path.join(ROOT, "BENCHMARK.json"))
+    bench["configs"].append({"name": TOY, "source": TOY_CONFIG["source"],
+                             "file": f"bench/configs/{TOY}.json",
+                             "reduced": [], "why": TOY_CELL["why"]})
+    bench["workloads"].append(TOY_CELL)
+    for m in bench["end_to_end"]:
+        if BO in m.get("workloads", []):
+            m["workloads"].append(TOY_CELL["name"])
+    new = {"BENCHMARK.json": json.dumps(bench),
+           f"bench/configs/{TOY}.json": json.dumps(TOY_CONFIG),
+           f"bench/tests/sizes/{TOY}.json": json.dumps(TOY_SIZES),
+           "bench/harness/graphs/sqrt_tree.py": TOY_EDGES}
+    for path, text in new.items():
+        full = os.path.join(tree, path)
+        assert path == "BENCHMARK.json" or not os.path.exists(full)
+        with open(full, "w") as fh:
+            fh.write(text)
+
+
+def test_a_new_generator_enters_as_new_files(tmp_path, monkeypatch):
+    from repro.graphs import generators, signals
+
+    monkeypatch.setattr(generators, "sqrt_tree", sqrt_tree, raising=False)
+    monkeypatch.setattr(signals, "degree_influence", degree_influence,
+                        raising=False)
+    tree, root = str(tmp_path / "tree"), str(tmp_path / "root")
+    _plant(tree)
+    monkeypatch.setattr(spec, "GRAPHS_DIR",
+                        os.path.join(tree, "bench", "harness", "graphs"))
+    _shrink(root, src=tree)
+    cfg = spec.load_json(os.path.join(root, "bench", "configs",
+                                      f"{TOY}.json"))
+    assert cfg["graph"] == {"generator": "sqrt_tree", "n_nodes": 1200,
+                            "isolated": 64}
+    cell = spec.resolve(TOY_CELL["name"], root=root)
+    assert [m["name"] for m in cell.end_to_end] == ["bo_round_s", "setup_s"]
+    common.check_adjacency(cfg["graph"])
+    go = _on_the_cpu(root, monkeypatch, str(tmp_path / "trace"))
+    res = go(TOY_CELL["name"], seconds=1.0)
+    assert res["correct"], res["checks"]
+    assert res["attempted"] > 0 and res["failed"] == 0
+
+
+def test_every_configuration_has_its_sizes_file():
+    for c in common.configs():
+        assert os.path.isfile(common.sizes_file(c["name"])), c["name"]
+
+
+def test_a_configuration_without_sizes_is_named(tmp_path):
+    entry = {"name": "no_sizes", "file": "bench/configs/no_sizes.json"}
+    with pytest.raises(FileNotFoundError) as err:
+        common.at_test_size(entry, str(tmp_path))
+    assert common.sizes_file("no_sizes", str(tmp_path)) in str(err.value)
