@@ -123,7 +123,7 @@ def _sample_chunk_vals(graph, f, seed, start, chunk, n_rows, cfg):
     valid = (idx < n_rows).astype(jnp.float32)
     nodes = jnp.minimum(start + idx, graph.n_nodes - 1).astype(jnp.int32)
     cols, loads, lens = dispatch.walk_sample(
-        graph.neighbors, graph.weights, graph.deg, nodes, seed,
+        graph.walk_table, graph.deg, nodes, seed,
         n_walkers=cfg.n_walkers, p_halt=cfg.p_halt, l_max=cfg.l_max,
         reweight=cfg.reweight, scheme=cfg.scheme,
     )

@@ -118,7 +118,7 @@ def _sample(graph: Graph, nodes: jax.Array, seed: jax.Array,
     # trace, so flipping observability retraces with taps staged in/out.
     with obs.tap_scope(obs_tap), dispatch.use_backend(spmv_backend):
         cols, loads, lens = dispatch.walk_sample(
-            graph.neighbors, graph.weights, graph.deg, nodes, seed,
+            graph.walk_table, graph.deg, nodes, seed,
             n_walkers=cfg.n_walkers, p_halt=cfg.p_halt, l_max=cfg.l_max,
             reweight=cfg.reweight, scheme=cfg.scheme,
         )

@@ -200,15 +200,15 @@ def sharded_cg_solve_chunked(
     @functools.partial(
         shard_map_unchecked,
         mesh=mesh,
-        in_specs=(P(), P(), P(), P(), P(), row),
+        in_specs=(P(), P(), P(), row),
         out_specs=(row, P(), P()),
     )
-    def run(neighbors, weights, deg, f, seed, b_local):
+    def run(graph, f, seed, b_local):
         idx = jnp.zeros((), jnp.int32)
         for a in axes:
             idx = idx * mesh.shape[a] + jax.lax.axis_index(a)
         phi_local = linops.ChunkedPhiOperator(
-            Graph(neighbors, weights, deg), f, seed, walk, chunk,
+            graph, f, seed, walk, chunk,
             n_rows=n_local, row_start=idx * n_local,
         )
         khat = linops.KhatOperator(phi_local, phi_local,
@@ -217,9 +217,7 @@ def sharded_cg_solve_chunked(
         res = solvers.solve(h, b_local, strategy, dot=psum_dot(axes))
         return res.x, res.iters, jnp.all(res.converged)
 
-    x, iters, converged = run(
-        graph.neighbors, graph.weights, graph.deg, f, seed, b
-    )
+    x, iters, converged = run(graph, f, seed, b)
     if return_diagnostics:
         return x, iters, converged
     return x

@@ -2,7 +2,8 @@
 
 TPU/XLA require static shapes, so adjacency is stored in padded ELL form:
 ``neighbors[N, max_deg]`` / ``weights[N, max_deg]`` with zero-weight padding.
-This is the walk-sampling substrate for the GRF estimator (DESIGN.md §3).
+The walk sampler reads the same slots through one packed table, so that a
+walk move is one row gather (DESIGN.md §3.6).
 """
 from __future__ import annotations
 
@@ -23,11 +24,19 @@ class Graph:
       neighbors: int32[N, max_deg] — padded with 0 beyond ``deg[i]``.
       weights:   float32[N, max_deg] — walk-matrix entries; 0 beyond ``deg[i]``.
       deg:       int32[N] — unweighted node degrees (Alg. 2's ``d``).
+      walk_table: int32[N·max_deg, 3] — per ELL slot ``i·max_deg + k``, the
+        walk sampler's whole move: ``neighbors[i, k]``, the bits of
+        ``weights[i, k]``, and ``deg[neighbors[i, k]]`` (padding slots hold
+        node 0 and its degree).
+
+    Build one with :func:`from_edges` or :func:`from_ell`, which derive the
+    table from the other three.
     """
 
     neighbors: jax.Array
     weights: jax.Array
     deg: jax.Array
+    walk_table: jax.Array
 
     @property
     def n_nodes(self) -> int:
@@ -38,7 +47,7 @@ class Graph:
         return self.neighbors.shape[1]
 
     def tree_flatten(self):
-        return (self.neighbors, self.weights, self.deg), None
+        return (self.neighbors, self.weights, self.deg, self.walk_table), None
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -96,11 +105,21 @@ def from_edges(
     slot = np.arange(len(src_s)) - row_start[src_s]
     neighbors[src_s, slot] = dst_s
     wmat[src_s, slot] = w_s
-    return Graph(
-        neighbors=jnp.asarray(neighbors),
-        weights=jnp.asarray(wmat),
-        deg=jnp.asarray(deg.astype(np.int32)),
-    )
+    return from_ell(neighbors, wmat, deg)
+
+
+def from_ell(neighbors, weights, deg) -> Graph:
+    """A :class:`Graph` from its padded ELL arrays, with the walk table
+    derived from them.  The table is built on the host, so the device holds
+    only the finished arrays (no intermediate of the packing)."""
+    neighbors = np.asarray(neighbors, np.int32)
+    weights = np.asarray(weights, np.float32)
+    deg = np.asarray(deg, np.int32)
+    walk_table = np.stack(
+        [neighbors, weights.view(np.int32), deg[neighbors]], axis=-1
+    ).reshape(-1, 3)
+    return Graph(jnp.asarray(neighbors), jnp.asarray(weights),
+                 jnp.asarray(deg), jnp.asarray(walk_table))
 
 
 def to_dense(graph: Graph) -> jax.Array:

@@ -263,11 +263,12 @@ def woodbury_apply(b, dinv, einv, v, *, backend: str | None = None):
 
 @scoped(SCOPES["walk_sample"])
 def walk_sample(
-    neighbors, weights, deg, nodes, seed,
+    walk_table, deg, nodes, seed,
     *, n_walkers: int, p_halt: float, l_max: int, reweight: bool = True,
     scheme: str = "iid", backend: str | None = None,
 ):
-    """(cols, loads, lens) = GRF walk deposits for ``nodes`` in ELL layout.
+    """(cols, loads, lens) = GRF walk deposits for ``nodes`` in ELL layout,
+    walked over a graph's ``walk_table`` and ``deg`` (graphs/formats.Graph).
 
     The counter-based RNG (kernels/walk_sampler/rng.py) is keyed on the
     absolute start-node id, so the result is independent of how ``nodes``
@@ -291,12 +292,12 @@ def walk_sample(
     )
     if backend == "xla":
         return ops.walk_sample_xla(
-            neighbors, weights, deg, nodes, seed,
+            walk_table, deg, nodes, seed,
             n_walkers=n_walkers, p_halt=p_halt, l_max=l_max, reweight=reweight,
             scheme=scheme,
         )
     return ops.walk_sample_pallas(
-        neighbors, weights, deg, nodes, seed,
+        walk_table, deg, nodes, seed,
         n_walkers=n_walkers, p_halt=p_halt, l_max=l_max, reweight=reweight,
         scheme=scheme, interpret=_interpret(backend),
     )

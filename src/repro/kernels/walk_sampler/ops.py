@@ -11,22 +11,22 @@ from .walk_sampler import walk_sample as _walk_sample_kernel
 
 
 def walk_sample_xla(
-    neighbors, weights, deg, nodes, seed,
+    walk_table, deg, nodes, seed,
     *, n_walkers, p_halt, l_max, reweight=True, scheme="iid",
 ):
     return walk_sample_ref(
-        neighbors, weights, deg, nodes, seed,
+        walk_table, deg, nodes, seed,
         n_walkers=n_walkers, p_halt=p_halt, l_max=l_max, reweight=reweight,
         scheme=scheme,
     )
 
 
 def walk_sample_pallas(
-    neighbors, weights, deg, nodes, seed,
+    walk_table, deg, nodes, seed,
     *, n_walkers, p_halt, l_max, reweight=True, scheme="iid", interpret=False,
 ):
     return _walk_sample_kernel(
-        neighbors, weights, deg, nodes, seed,
+        walk_table, deg, nodes, seed,
         n_walkers=n_walkers, p_halt=p_halt, l_max=l_max, reweight=reweight,
         scheme=scheme, interpret=interpret,
     )

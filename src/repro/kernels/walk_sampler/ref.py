@@ -27,13 +27,13 @@ termination is scheme-dependent; directional choices stay iid, so the walk
 from __future__ import annotations
 
 import jax.numpy as jnp
+from jax import lax
 
 from . import rng
 
 
 def walk_block(
-    neighbors: jnp.ndarray,   # int32[N, D] padded adjacency
-    weights: jnp.ndarray,     # float32[N, D] walk-matrix entries
+    walk_table: jnp.ndarray,  # int32[N·D, 3]: neighbour, weight bits, its deg
     deg: jnp.ndarray,         # int32[N]
     nodes: jnp.ndarray,       # int32[M] absolute start-node ids
     seed: jnp.ndarray,        # uint32 scalar
@@ -49,18 +49,21 @@ def walk_block(
     Outputs are [M, K] with K = n_walkers·(l_max+1); loads are already
     divided by n_walkers (the estimator's 1/n).  Pure jnp — the Pallas
     kernel runs this exact function per VMEM block.
+
+    A move is one gather of a ``walk_table`` row (``Graph.walk_table``):
+    the next node, the edge's weight and the next node's degree, which the
+    following move needs.  ``deg`` is read once, at the start nodes.
     """
     if scheme not in rng.SCHEMES:
         raise ValueError(f"unknown walk scheme {scheme!r}; valid: {rng.SCHEMES}")
     m = nodes.shape[0]
-    max_deg = neighbors.shape[1]
-    nbr_flat = neighbors.reshape(-1)
-    wgt_flat = weights.reshape(-1)
+    max_deg = walk_table.shape[0] // deg.shape[0]
 
     node_u = nodes.astype(jnp.uint32)[:, None]              # [M, 1]
     walker_u = jnp.arange(n_walkers, dtype=jnp.uint32)[None, :]
 
     cur = jnp.broadcast_to(nodes[:, None], (m, n_walkers)).astype(jnp.int32)
+    d = jnp.broadcast_to(jnp.take(deg, nodes)[:, None], (m, n_walkers))
     load = jnp.ones((m, n_walkers), jnp.float32)
     alive = jnp.ones((m, n_walkers), jnp.float32)
 
@@ -77,15 +80,15 @@ def walk_block(
         else:
             loads_steps.append(load * alive)
         u_choice = rng.counter_uniform(seed, node_u, walker_u, 2 * step)
-        d = jnp.take(deg, cur)                              # [M, W]
         # Guard isolated nodes: degree 0 ⇒ stay on padding with zero load.
         choice = jnp.minimum(
             (u_choice * d.astype(jnp.float32)).astype(jnp.int32),
             jnp.maximum(d - 1, 0),
         )
         flat = cur * max_deg + choice
-        nxt = jnp.take(nbr_flat, flat)
-        w = jnp.take(wgt_flat, flat)
+        edge = jnp.take(walk_table, flat, axis=0)          # [M, W, 3]
+        nxt = edge[..., 0]
+        w = lax.bitcast_convert_type(edge[..., 1], jnp.float32)
         if reweight:
             load = load * d.astype(jnp.float32) / (1.0 - p_halt) * w
         else:
@@ -96,7 +99,7 @@ def walk_block(
             )
             alive = alive * (u_halt >= p_halt).astype(jnp.float32)
         alive = alive * (d > 0).astype(jnp.float32)
-        cur = nxt
+        cur, d = nxt, edge[..., 2]
 
     k = n_walkers * (l_max + 1)
     cols = jnp.stack(cols_steps, axis=-1).reshape(m, k).astype(jnp.int32)

@@ -2,10 +2,11 @@
 
 Grid: (M // BM,) over start-node blocks.  Per grid step:
 
-  * the adjacency substrate (``neighbors``/``weights`` [N, D], ``deg`` [N])
-    is pinned to block 0 so it stays *VMEM-resident across the whole grid*
-    — every per-step neighbour gather (``jnp.take`` over the flattened row
-    slice) runs at on-chip latency, never touching HBM;
+  * the walk substrate (the packed per-slot ``walk_table`` [N·D, 3] of
+    neighbour, weight bits and neighbour degree, and ``deg`` [N]) is pinned
+    to block 0 so it stays *VMEM-resident across the whole grid* — every
+    per-move row gather (``jnp.take`` over the table) runs at on-chip
+    latency, never touching HBM;
   * randomness is the counter hash from rng.py addressed by
     (seed, start node, walker, step) — no RNG state crosses grid steps, so
     blocks are order-independent and chunked sampling is bit-identical to
@@ -14,18 +15,18 @@ Grid: (M // BM,) over start-node blocks.  Per grid step:
     (cols, loads, lens) outputs *directly in ELL layout* [BM, K],
     K = n_walkers·(l_max+1) — the trace never exists in any other format.
 
-Per-step VMEM: N·D·8 + N·4 (resident substrate) + 3·BM·K·4 (outputs) bytes.
-The substrate residency bounds the compiled path to N·(2·max_deg+1)·4 ≲
-VMEM; beyond that route through the ``"xla"`` backend (kernels/dispatch.py)
-or shrink max_deg — the *driver-level* node chunking in core/walks.py is
-orthogonal and works on every backend.
+Per-step VMEM: N·D·12 + N·4 (resident substrate) + 3·BM·K·4 (outputs)
+bytes.  The substrate residency bounds the compiled path to
+N·(3·max_deg+1)·4 ≲ VMEM; beyond that route through the ``"xla"`` backend
+(kernels/dispatch.py) or shrink max_deg — the node chunking above the
+kernel, in core/walks.py, is orthogonal and works on every backend.
 
 The step math itself is ref.walk_block — the kernel and the jnp oracle
 evaluate the same function, so parity is exact, not statistical.
 
 **Not used on TPU.**  Mosaic refuses the neighbour ``jnp.take`` ("Only 2D
-gather is supported"), and the resident adjacency of ``ring(10⁶, k=3)`` is
-52 MB against 16 MB of VMEM.  kernels/dispatch.py runs walk sampling
+gather is supported"), and the resident substrate of ``ring(10⁶, k=3)`` is
+76 MB against 16 MB of VMEM.  kernels/dispatch.py runs walk sampling
 through its ``"xla"`` implementation on TPU; the kernel is exercised through
 the interpreter.
 """
@@ -43,12 +44,12 @@ DEFAULT_BM = 256
 
 
 def _walk_kernel(
-    nodes_ref, seed_ref, nbr_ref, wgt_ref, deg_ref,
+    nodes_ref, seed_ref, table_ref, deg_ref,
     cols_ref, loads_ref, lens_ref,
     *, n_walkers, p_halt, l_max, reweight, scheme,
 ):
     cols, loads, lens = walk_block(
-        nbr_ref[:], wgt_ref[:], deg_ref[:], nodes_ref[:], seed_ref[0],
+        table_ref[:], deg_ref[:], nodes_ref[:], seed_ref[0],
         n_walkers=n_walkers, p_halt=p_halt, l_max=l_max, reweight=reweight,
         scheme=scheme,
     )
@@ -63,8 +64,7 @@ def _walk_kernel(
                      "block_m", "interpret"),
 )
 def walk_sample(
-    neighbors: jax.Array,
-    weights: jax.Array,
+    walk_table: jax.Array,
     deg: jax.Array,
     nodes: jax.Array,
     seed: jax.Array,
@@ -79,7 +79,7 @@ def walk_sample(
 ):
     """Sample walks for ``nodes``; returns (cols, loads, lens) [M, K]."""
     m = nodes.shape[0]
-    n, max_deg = neighbors.shape
+    n = deg.shape[0]
     k = n_walkers * (l_max + 1)
 
     bm = min(block_m, max(8, m))
@@ -101,8 +101,7 @@ def walk_sample(
         in_specs=[
             pl.BlockSpec((bm,), lambda i: (i,)),
             pl.BlockSpec((1,), lambda i: (0,)),
-            pl.BlockSpec((n, max_deg), lambda i: (0, 0)),
-            pl.BlockSpec((n, max_deg), lambda i: (0, 0)),
+            pl.BlockSpec(walk_table.shape, lambda i: (0, 0)),
             pl.BlockSpec((n,), lambda i: (0,)),
         ],
         out_specs=(out_spec, out_spec, out_spec),
@@ -115,7 +114,7 @@ def walk_sample(
     )(
         nodes.astype(jnp.int32),
         jnp.asarray(seed, jnp.uint32).reshape(1),
-        neighbors, weights.astype(jnp.float32), deg,
+        walk_table, deg,
     )
     if pad_m:
         return cols[:m], loads[:m], lens[:m]

@@ -161,7 +161,7 @@ def query_rows(state: ServeState, query_nodes: jax.Array) -> WalkTrace:
     the rows of the Φ the train block was built from — no trace is stored
     for them anywhere."""
     cols, loads, lens = dispatch.walk_sample(
-        state.graph.neighbors, state.graph.weights, state.graph.deg,
+        state.graph.walk_table, state.graph.deg,
         query_nodes.astype(jnp.int32), state.seed,
         n_walkers=state.cfg.n_walkers, p_halt=state.cfg.p_halt,
         l_max=state.cfg.l_max, reweight=state.cfg.reweight,

@@ -2,17 +2,22 @@
 
 Each case compiles one kernel at the shapes ``chip_smoke.py`` drives, for a
 described (not attached) v5e, so a kernel the chip's compiler would refuse
-fails here without chip time.  The topology is described inside a fixture,
+fails here without chip time.  The XLA walk sampler is compiled too, and
+its gathers counted.  The topology is described inside a fixture,
 never at import, so only the worker that runs this file loads the TPU
 compiler.  The persistent compilation cache stays off around these
 compiles: their entries cannot be read back without a chip.
 """
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.kernels import dispatch
 from repro.kernels.gram_block.gram_block import gram_block
+from repro.kernels.walk_sampler.ref import walk_block
 from repro.kernels.woodbury_apply.woodbury_apply import woodbury_apply
 
 T_TRAIN = 4000          # 4√N training rows at N = 10⁶
@@ -67,6 +72,25 @@ def test_gram_block_compiles_for_v5e(one_chip, rows, cols):
         s(one_chip, (rows, K)), s(one_chip, (rows, K), jnp.int32),
         s(one_chip, (cols, K)), s(one_chip, (cols, K), jnp.int32),
     )
+
+
+def test_walk_block_gathers_once_per_move_for_v5e(one_chip):
+    """The BO draw's walk chunk (10⁶-node grid, max degree 4, 65,536 start
+    nodes × 30 walkers, l_max 5) compiles to one gather over the start
+    nodes' degrees and one packed-row gather per live move: l_max + 1.
+    Three reads per move (degree, neighbour, weight) would be 3·l_max."""
+    n, max_deg, m, l_max = 10**6, 4, 65536, 5
+    fn = jax.jit(functools.partial(walk_block, n_walkers=30, p_halt=0.15,
+                                   l_max=l_max))
+    text = fn.lower(
+        _spec(one_chip, (n * max_deg, 3), jnp.int32),
+        _spec(one_chip, (n,), jnp.int32),
+        _spec(one_chip, (m,), jnp.int32),
+        _spec(one_chip, (), jnp.uint32),
+    ).compile().as_text()
+    gathers = [line for line in text.splitlines()
+               if re.search(r"\bgather\(", line)]
+    assert len(gathers) == l_max + 1, gathers
 
 
 @pytest.mark.parametrize("rhs", [R, None])

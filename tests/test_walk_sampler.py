@@ -1,13 +1,15 @@
 """Walk-sampler kernel: oracle parity, deposit statistics, chunked paths."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import features, linops, modulation, walks
-from repro.graphs import generators
+from repro.graphs import formats, generators
 from repro.kernels import dispatch
-from repro.kernels.walk_sampler import walk_sample, walk_sample_ref
+from repro.kernels.walk_sampler import rng, walk_sample, walk_sample_ref
 
 
 @pytest.fixture(scope="module")
@@ -34,8 +36,8 @@ def test_kernel_matches_oracle(grid100):
     g = grid100
     nodes = jnp.arange(g.n_nodes, dtype=jnp.int32)
     seed = jnp.uint32(99)
-    ref = walk_sample_ref(g.neighbors, g.weights, g.deg, nodes, seed, **CFG)
-    ker = walk_sample(g.neighbors, g.weights, g.deg, nodes, seed,
+    ref = walk_sample_ref(g.walk_table, g.deg, nodes, seed, **CFG)
+    ker = walk_sample(g.walk_table, g.deg, nodes, seed,
                       interpret=True, **CFG)
     _assert_traces_match(ref, ker)
 
@@ -45,8 +47,8 @@ def test_kernel_block_size_invariance(grid100, block_m):
     g = grid100
     nodes = jnp.arange(37, dtype=jnp.int32)  # non-multiple of every block
     seed = jnp.uint32(7)
-    ref = walk_sample_ref(g.neighbors, g.weights, g.deg, nodes, seed, **CFG)
-    ker = walk_sample(g.neighbors, g.weights, g.deg, nodes, seed,
+    ref = walk_sample_ref(g.walk_table, g.deg, nodes, seed, **CFG)
+    ker = walk_sample(g.walk_table, g.deg, nodes, seed,
                       block_m=block_m, interpret=True, **CFG)
     _assert_traces_match(ref, ker)
 
@@ -67,7 +69,7 @@ def test_deposit_distribution_backends_match(grid100):
         for s in range(40):
             with dispatch.use_backend(backend):
                 cols, loads, lens = dispatch.walk_sample(
-                    g.neighbors, g.weights, g.deg, start,
+                    g.walk_table, g.deg, start,
                     jnp.uint32(seed0 + s), **kw,
                 )
             c = np.array(cols).reshape(64, 2)[:, 1]  # the l=1 deposit column
@@ -172,13 +174,93 @@ def test_chunked_pathwise_equals_monolithic(grid100, padded):
 
 def test_isolated_node_zero_load():
     """Degree-0 nodes deposit their own start (l=0) then go dead."""
-    from repro.graphs.formats import Graph
+    from repro.graphs.formats import from_ell
 
     g = generators.ring(8, k=1)
-    iso = Graph(neighbors=g.neighbors, weights=g.weights,
-                deg=g.deg.at[3].set(0))
+    iso = from_ell(g.neighbors, g.weights, g.deg.at[3].set(0))
     tr = walks.sample_walks(iso, jax.random.PRNGKey(0), n_walkers=4,
                             p_halt=0.2, l_max=3)
     loads = np.array(tr.loads).reshape(8, 4, 4)
     assert (loads[3, :, 0] != 0).all()      # the l=0 self-deposit survives
     assert (loads[3, :, 1:] == 0).all()     # everything after is masked
+
+
+def _three_table_walk_block(neighbors, weights, deg, nodes, seed, *,
+                            n_walkers, p_halt, l_max, scheme):
+    """The sampler as it was before the packed walk table: each move read
+    ``deg[cur]``, ``neighbors[flat]`` and ``weights[flat]``.  Frozen here as
+    the reference the packed table must reproduce bit for bit."""
+    m = nodes.shape[0]
+    max_deg = neighbors.shape[1]
+    nbr_flat = neighbors.reshape(-1)
+    wgt_flat = weights.reshape(-1)
+    node_u = nodes.astype(jnp.uint32)[:, None]
+    walker_u = jnp.arange(n_walkers, dtype=jnp.uint32)[None, :]
+    cur = jnp.broadcast_to(nodes[:, None], (m, n_walkers)).astype(jnp.int32)
+    load = jnp.ones((m, n_walkers), jnp.float32)
+    alive = jnp.ones((m, n_walkers), jnp.float32)
+    cols_steps, loads_steps = [], []
+    for step in range(l_max + 1):
+        cols_steps.append(cur)
+        if scheme == "grfspp":
+            loads_steps.append(
+                load * alive * jnp.float32((1.0 - p_halt) ** step))
+        else:
+            loads_steps.append(load * alive)
+        u_choice = rng.counter_uniform(seed, node_u, walker_u, 2 * step)
+        d = jnp.take(deg, cur)
+        choice = jnp.minimum(
+            (u_choice * d.astype(jnp.float32)).astype(jnp.int32),
+            jnp.maximum(d - 1, 0),
+        )
+        flat = cur * max_deg + choice
+        nxt = jnp.take(nbr_flat, flat)
+        w = jnp.take(wgt_flat, flat)
+        load = load * d.astype(jnp.float32) / (1.0 - p_halt) * w
+        if scheme != "grfspp":
+            u_halt = rng.halt_uniform(seed, node_u, walker_u, 2 * step + 1,
+                                      scheme=scheme)
+            alive = alive * (u_halt >= p_halt).astype(jnp.float32)
+        alive = alive * (d > 0).astype(jnp.float32)
+        cur = nxt
+    k = n_walkers * (l_max + 1)
+    cols = jnp.stack(cols_steps, axis=-1).reshape(m, k).astype(jnp.int32)
+    loads = (jnp.stack(loads_steps, axis=-1) / n_walkers).reshape(m, k)
+    lens = jnp.broadcast_to(
+        jnp.arange(l_max + 1, dtype=jnp.int32), (m, n_walkers, l_max + 1)
+    ).reshape(m, k)
+    return cols, loads, lens
+
+
+def _weighted_with_isolated():
+    """A raw-weight (``normalize=False``) random graph whose last 15 of 60
+    nodes have no edge."""
+    r = np.random.default_rng(5)
+    edges = r.integers(0, 45, size=(120, 2))
+    edges = edges[edges[:, 0] != edges[:, 1]]
+    return formats.from_edges(edges, 60, weights=r.uniform(0.1, 2.0, len(edges)),
+                              normalize=False)
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas-interpret"])
+@pytest.mark.parametrize("graph", ["grid2d", "weighted_isolated"])
+@pytest.mark.parametrize("scheme", ["iid", "antithetic", "qmc", "grfspp"])
+def test_packed_table_equals_three_table_step(scheme, graph, backend):
+    """One row gather per move from ``Graph.walk_table`` gives the same
+    cols, loads and lens, to the bit, as the three-table step: the same
+    degree and weight values enter the same arithmetic in the same order,
+    isolated nodes and padding slots included."""
+    g = (generators.grid2d(10, 12) if graph == "grid2d"
+         else _weighted_with_isolated())
+    assert int(jnp.min(g.deg)) == (2 if graph == "grid2d" else 0)
+    nodes = jnp.arange(g.n_nodes, dtype=jnp.int32)
+    seed = jnp.uint32(3616000001)
+    kw = dict(n_walkers=30, p_halt=0.15, l_max=5, scheme=scheme)
+    want = jax.jit(functools.partial(_three_table_walk_block, **kw))(
+        g.neighbors, g.weights, g.deg, nodes, seed)
+    got = jax.jit(functools.partial(dispatch.walk_sample, backend=backend,
+                                    **kw))(g.walk_table, g.deg, nodes, seed)
+    for w, o in zip(want, got):
+        assert w.dtype == o.dtype
+        np.testing.assert_array_equal(np.asarray(w).view(np.uint32),
+                                      np.asarray(o).view(np.uint32))

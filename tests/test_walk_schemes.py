@@ -164,8 +164,8 @@ def test_kernel_matches_oracle_per_scheme(grid100, scheme):
     nodes = jnp.arange(37, dtype=jnp.int32)
     seed = jnp.uint32(99)
     kw = dict(n_walkers=6, p_halt=0.25, l_max=4, scheme=scheme)
-    ref = walk_sample_ref(g.neighbors, g.weights, g.deg, nodes, seed, **kw)
-    ker = walk_sample(g.neighbors, g.weights, g.deg, nodes, seed,
+    ref = walk_sample_ref(g.walk_table, g.deg, nodes, seed, **kw)
+    ker = walk_sample(g.walk_table, g.deg, nodes, seed,
                       block_m=8, interpret=True, **kw)
     np.testing.assert_array_equal(np.array(ref[0]), np.array(ker[0]))
     np.testing.assert_array_equal(np.array(ref[2]), np.array(ker[2]))

@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.core import features, kernels_exact, modulation, walks
-from repro.graphs import generators
+from repro.graphs import formats, generators
 
 
 @pytest.fixture(scope="module")
@@ -92,3 +92,39 @@ def test_subset_walks_match_full(ring32):
                                       n_walkers=5, p_halt=0.2, l_max=4)
     assert tr.cols.shape == (3, 5 * 5)
     assert np.isfinite(np.asarray(tr.loads)).all()
+
+
+def _raw_weights_with_isolated():
+    r = np.random.default_rng(11)
+    edges = r.integers(0, 40, size=(90, 2))
+    edges = edges[edges[:, 0] != edges[:, 1]]
+    return formats.from_edges(edges, 50, weights=r.uniform(0.1, 3.0, len(edges)),
+                              normalize=False)
+
+
+def _rebuilt_from_ell():
+    g = _raw_weights_with_isolated()
+    return formats.from_ell(g.neighbors, g.weights, g.deg)
+
+
+@pytest.mark.parametrize("build", [
+    lambda: generators.grid2d(7, 9), _raw_weights_with_isolated,
+    _rebuilt_from_ell,
+], ids=["from_edges_grid", "from_edges_weighted_isolated", "from_ell"])
+def test_walk_table_packs_each_slot(build):
+    """Row i·max_deg + k of ``walk_table`` is (neighbors[i, k], the bits of
+    weights[i, k], deg[neighbors[i, k]]), padding slots included: they hold
+    node 0 and its degree."""
+    g = build()
+    nbr, wgt = np.asarray(g.neighbors), np.asarray(g.weights)
+    deg, table = np.asarray(g.deg), np.asarray(g.walk_table)
+    assert table.dtype == np.int32
+    assert table.shape == (g.n_nodes * g.max_deg, 3)
+    np.testing.assert_array_equal(table[:, 0], nbr.reshape(-1))
+    np.testing.assert_array_equal(table[:, 1],
+                                  wgt.astype(np.float32).view(np.int32).reshape(-1))
+    np.testing.assert_array_equal(table[:, 2], deg[nbr].reshape(-1))
+    pad = np.arange(g.max_deg)[None, :] >= deg[:, None]
+    if pad.any():
+        assert (table[pad.reshape(-1), 0] == 0).all()
+        assert (table[pad.reshape(-1), 2] == deg[0]).all()
