@@ -73,7 +73,6 @@ def main() -> None:
         bench_solvers,
         bench_spmv,
         bench_walks,
-        roofline,
     )
 
     suites = [
@@ -91,7 +90,6 @@ def main() -> None:
         ("regression (Fig 3)", bench_regression),
         ("bo (Fig 4)", bench_bo),
         ("classification (Table 7)", bench_classification),
-        ("roofline (§Roofline)", roofline),
     ]
     import jax
 

@@ -321,7 +321,7 @@ def mesh_phase(graph, check: Checks, n_chips: int):
     from repro import serving, solvers
     from repro.core import linops, modulation, walks
     from repro.distributed import gp_shard
-    from repro.launch.mesh import make_serving_mesh
+    from repro.serving.sharded import make_serving_mesh
 
     cfg = walk_config()
     mod = modulation.diffusion(l_max=cfg.l_max)

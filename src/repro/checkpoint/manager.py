@@ -1,4 +1,4 @@
-"""Fault-tolerant checkpointing (DESIGN.md §5).
+"""Fault-tolerant checkpointing (DESIGN.md §3.11).
 
 Step-tagged directories with atomic commit (write tmp → fsync → rename), a
 MANIFEST for integrity, async save thread, keep-N GC, and *elastic* restore:

@@ -51,21 +51,10 @@ def _data_axes(mesh: Mesh) -> tuple[str, ...]:
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
 
-def psum_reduce(axes: Sequence[str], compress: bool = False):
-    """The all-reduce injected as the operators' ``reduce`` hook.
-
-    ``compress`` casts the per-iteration N-vector all-reduce to bf16.
-    §Perf verdict: REFUTED as a wire optimisation — jax/XLA upcasts bf16
-    psum operands to f32 before the all-reduce (verified in HLO:
-    ``f32[...] all-reduce(convert(...))``), so wire bytes are unchanged.
-    Kept for documentation; true compression needs a custom collective
-    (bf16 all-gather + local reduction) — future work."""
+def psum_reduce(axes: Sequence[str]):
+    """The all-reduce injected as the operators' ``reduce`` hook."""
 
     def reduce(partial):
-        if compress:
-            return jax.lax.psum(partial.astype(jnp.bfloat16), axes).astype(
-                jnp.float32
-            )
         return jax.lax.psum(partial, axes)
 
     return reduce
@@ -103,12 +92,11 @@ def sharded_h_operator(
     axes: Sequence[str],
     sigma_n2,
     mask: jax.Array | None = None,
-    compress: bool = False,
 ) -> linops.ShiftedOperator:
     """H = (M) K̂ (M) + D over locally-owned Φ rows, psum-reduced."""
     return linops.shifted(
         trace_local, f, sigma_n2, n_nodes,
-        mask=mask, reduce=psum_reduce(axes, compress),
+        mask=mask, reduce=psum_reduce(axes),
     )
 
 
@@ -121,14 +109,13 @@ def sharded_cg_solve(
     tol: float | None = None,
     max_iters: int | None = None,
     fixed_unrolled: bool = False,
-    compress: bool = False,
     strategy: SolveStrategy | None = None,
     return_diagnostics: bool = False,
 ):
     """Solve (K̂ + σ²I) v = b with Φ rows sharded over (pod, data).
 
-    ``fixed_unrolled`` runs exactly ``max_iters`` unrolled iterations — used
-    by the dry-run so cost_analysis sees every psum (DESIGN.md §5).
+    ``fixed_unrolled`` runs exactly ``max_iters`` unrolled iterations, so
+    cost_analysis sees every psum.
     ``return_diagnostics=True`` additionally returns (iters_used,
     converged) — identical on every shard (the convergence test runs on
     psum-reduced dots), so they replicate."""
@@ -146,8 +133,7 @@ def sharded_cg_solve(
     )
     def run(cols, loads, lens, f, b_local):
         local = WalkTrace(cols, loads, lens)
-        h = sharded_h_operator(local, f, n_nodes, axes, sigma_n2,
-                               compress=compress)
+        h = sharded_h_operator(local, f, n_nodes, axes, sigma_n2)
         res = solvers.solve(
             h, b_local, strategy, dot=psum_dot(axes), unroll=fixed_unrolled,
         )

@@ -1,5 +1,4 @@
-from . import cg, exact, mll, posterior, variational  # noqa: F401
-from .cg import CGResult, cg_solve  # noqa: F401  (deprecation shim)
+from . import exact, mll, posterior, variational  # noqa: F401
 from ..solvers import (  # noqa: F401  (the Krylov strategy layer)
     SolveStrategy,
     cg_solve_fixed,

@@ -1,8 +1,6 @@
 """AdamW + schedules (optax is not available in this environment).
 
-Pytree-generic; used by GP hyperparameter learning, SVGP, and LM training.
-Optimizer state shardings follow the parameter sharding rules (or the ZeRO-1
-override) produced in repro/launch/sharding.py.
+Pytree-generic; used by GP hyperparameter learning and SVGP.
 """
 from __future__ import annotations
 

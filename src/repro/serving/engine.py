@@ -1,17 +1,16 @@
 """Micro-batching GP query engine (DESIGN.md §3.7).
 
-The same production shape as launch/serve.ServeLoop — fixed-capacity
-request slots, admission, one jitted batched step — but the "decode step"
-is a GP posterior query: each wave lazily samples Φ rows for the slot
-nodes (dispatch.walk_sample subset mode), takes one cross-Gram block
+Fixed-capacity request slots, admission, one jitted batched step, where
+the step is a GP posterior query: each wave lazily samples Φ rows for the
+slot nodes (dispatch.walk_sample subset mode), takes one cross-Gram block
 against the VMEM-resident train rows (kernels/gram_block), and answers
 mean / variance / Thompson-draw requests from the cached Cholesky.  No CG
 anywhere; a wave is O(q·K²·m + q·m²) regardless of N.
 
 Request node-ids are admitted *individually* into slots, so a 1000-node
 request simply spans several waves of a batch-64 engine — the GP analogue
-of continuous batching (per-slot state is just the node id, so unlike the
-LM ServeLoop there is no same-length admission constraint).
+of continuous batching (per-slot state is just the node id, so there is
+no same-length admission constraint).
 
 :func:`thompson_draw` is the batch-BO entry point: an exact *joint* MVN
 draw over a candidate set (posterior covariance from the same cross-Gram +

@@ -6,7 +6,7 @@ pays the eager wrapper's sync barriers (``block_on`` + several
 ``int(flag)`` device reads).  At N=10⁶ a 64-slot wave is ~8 ms of device
 work — comparable to the host-side admission/packing — so the sync loop
 leaves half the machine idle.  :class:`GPFleetLoop` is the overlapped
-front end, in the style of ``launch/serve.ServeLoop``:
+front end:
 
   * **Double-buffered waves** — wave k is dispatched without
     ``block_until_ready`` and reaped at the *start* of step k+1, so the

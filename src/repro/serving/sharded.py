@@ -49,11 +49,29 @@ from ..core import features
 from ..core.walks import WalkTrace
 from ..distributed.gp_shard import psum_reduce, shard_map_unchecked
 from ..kernels import dispatch
-from ..launch.mesh import make_serving_mesh
 from ..resilience import faults
 from . import update
 from .engine import _joint_draw_tail
 from .state import ServeState, _mean_whiten, _moments_tail, query_rows
+
+
+def make_serving_mesh(n_shards: int | None = None):
+    """1-D ``("data",)`` mesh over the first ``n_shards`` devices — the
+    shard layout of :class:`ShardedServeState`.
+
+    On CPU hosts the devices come from
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (set **before**
+    jax initialises — see tests/test_distributed_gp.py's subprocess
+    pattern)."""
+    devices = jax.devices()
+    n = n_shards or len(devices)
+    if n > len(devices):
+        raise ValueError(
+            f"requested {n} serving shards but only {len(devices)} devices "
+            "exist; set XLA_FLAGS=--xla_force_host_platform_device_count "
+            "before jax initialises for host meshes"
+        )
+    return jax.make_mesh((n,), ("data",), devices=devices[:n])
 
 
 def _state_specs(state: ServeState, axis: str) -> ServeState:

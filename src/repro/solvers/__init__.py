@@ -1,10 +1,9 @@
 """The Krylov strategy layer (DESIGN.md §3.8).
 
 Every CG construction in the repo — gp/mll, gp/posterior, distributed/
-gp_shard, bo/thompson, serving/update, launch's dry-run cell and the
-benchmarks — goes through :func:`solve` under a :class:`SolveStrategy`
-instead of hand-wiring tol/iters/preconditioner literals at the call site.
-``repro.gp.cg`` remains as a deprecation shim over this package.
+gp_shard, bo/thompson, serving/update and the benchmarks — goes through
+:func:`solve` under a :class:`SolveStrategy` instead of hand-wiring
+tol/iters/preconditioner literals at the call site.
 """
 from .cg import (  # noqa: F401
     CGResult,

@@ -3,7 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.gp.cg import cg_solve, cg_solve_fixed
+from repro.solvers import cg_solve, cg_solve_fixed
 
 
 def _spd(n, cond=100.0, seed=0):

@@ -6,9 +6,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import serving
 from repro.checkpoint import CheckpointManager
-from repro.configs import get_config, reduce_config
-from repro.launch.train import train_loop
+from repro.core import modulation, walks
+from repro.graphs import generators
+from repro.serving import update
 
 
 def test_save_restore_roundtrip(tmp_path):
@@ -47,23 +49,41 @@ def test_async_save(tmp_path):
     assert mgr.latest_step() == 7
 
 
-def test_kill_and_resume_training_is_bit_exact(tmp_path):
-    """10 straight steps == 6 steps + simulated preemption + resume."""
-    cfg = reduce_config(get_config("h2o-danube-1.8b"))
+def test_kill_and_resume_serving_state_is_bit_exact(tmp_path):
+    """10 straight observations == 6 + checkpoint + simulated preemption +
+    restore into a fresh template + 4 more, saving the mutable leaves the
+    way resilience/journal.py recovers them."""
+    cfg = walks.WalkConfig(n_walkers=6, p_halt=0.25, l_max=4)
+    g = generators.ring(64, k=2)
+    mod = modulation.diffusion(l_max=cfg.l_max)
+    f = mod(mod.init(jax.random.PRNGKey(1)))
+    rng = np.random.default_rng(3)
+    nodes = rng.choice(64, 10, replace=False).astype(np.int32)
+    ys = rng.standard_normal(10).astype(np.float32)
 
-    straight, _ = train_loop(cfg, steps=10, ckpt_dir=None, global_batch=2,
-                             seq_len=16, seed=3)
+    def template():
+        return serving.init_state(g, jax.random.PRNGKey(0), f, 0.05,
+                                  capacity=16, cfg=cfg)
 
-    d1 = str(tmp_path / "run")
-    train_loop(cfg, steps=6, ckpt_dir=d1, ckpt_every=3, global_batch=2,
-               seq_len=16, seed=3)
-    # 'preemption': a brand-new process would call train_loop again —
-    # it restores from step 6 and continues to 10.
-    resumed, _ = train_loop(cfg, steps=10, ckpt_dir=d1, ckpt_every=3,
-                            global_batch=2, seq_len=16, seed=3)
+    straight = serving.observe_batch(template(), nodes, ys)
 
-    for a, b in zip(jax.tree.leaves(straight.params), jax.tree.leaves(resumed.params)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6, atol=1e-6)
+    d = str(tmp_path / "run")
+    first = serving.observe_batch(template(), nodes[:6], ys[:6])
+    CheckpointManager(d).save(6, update._pack(first))
+    # 'preemption': a brand-new process rebuilds the empty state and
+    # restores the latest checkpoint into it.
+    example = template()
+    packed, manifest = CheckpointManager(d).restore(update._pack(example))
+    assert manifest["step"] == 6
+    resumed = serving.observe_batch(update._unpack(example, packed),
+                                    nodes[6:], ys[6:])
+
+    for a, b in zip(jax.tree.leaves(straight), jax.tree.leaves(resumed)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    q = jnp.arange(64, dtype=jnp.int32)
+    for a, b in zip(serving.posterior_moments(straight, q),
+                    serving.posterior_moments(resumed, q)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_elastic_restore_shape_check(tmp_path):

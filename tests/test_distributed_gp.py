@@ -11,7 +11,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.graphs import generators
 from repro.core import walks, features, modulation
-from repro.gp.cg import cg_solve
+from repro.solvers import cg_solve
 from repro.gp.mll import make_h_matvec
 from repro.distributed.gp_shard import (
     sharded_cg_solve, sharded_cg_solve_chunked, sharded_posterior_sample)
@@ -29,7 +29,7 @@ got = sharded_cg_solve(tr, f, b, mesh, sigma_n2=0.1, tol=1e-7, max_iters=300)
 err = float(jnp.abs(want - got).max())
 assert err < 1e-3, f"cg mismatch {err}"
 
-# fixed/unrolled variant (dry-run path)
+# fixed/unrolled variant
 got_fx = sharded_cg_solve(tr, f, b, mesh, sigma_n2=0.1, max_iters=64,
                           fixed_unrolled=True)
 err = float(jnp.abs(want - got_fx).max())

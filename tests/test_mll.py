@@ -88,7 +88,7 @@ def test_masked_padding_matches_unpadded(problem):
     f = mod(params["mod"])
     s2 = mll.noise_var(params)
 
-    from repro.gp.cg import cg_solve
+    from repro.solvers import cg_solve
 
     tr_x = features.take_rows(tr, train)
     mv = mll.make_h_matvec(tr_x, f, s2, n)

@@ -93,7 +93,7 @@ def test_jlt_woodbury_solver(problem):
     g, tr, f, train, y, s2, *_ = problem
     n = g.n_nodes
     tr_x = features.take_rows(tr, train)
-    from repro.gp.cg import cg_solve
+    from repro.solvers import cg_solve
     from repro.gp.mll import make_h_matvec
 
     want = cg_solve(make_h_matvec(tr_x, f, s2, n), y, tol=1e-7, max_iters=500).x

@@ -1,4 +1,4 @@
-"""The solvers/ strategy layer: preconditioning, warm starts, SLQ, shims.
+"""The solvers/ strategy layer: preconditioning, warm starts, SLQ.
 
 Contract under test (ISSUE 5 acceptance):
   * Nyström-preconditioned and warm-started strategy solves match the dense
@@ -7,10 +7,8 @@ Contract under test (ISSUE 5 acceptance):
     the SLQ-based exact LML within 5% of the dense LML on a 500-node graph;
   * preconditioning never changes the fixed point (hypothesis property);
   * the psum-``dot`` sharded path retains parity (tests/test_distributed_gp
-    covers the shard_map side; here the hook itself);
-  * ``repro.gp.cg`` keeps working as a deprecation shim.
+    covers the shard_map side; here the hook itself).
 """
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -263,53 +261,15 @@ def test_warm_started_fit_uses_fewer_total_cg_iters(system):
     assert all(r["cg_converged"] for r in warm.history)
 
 
-def test_gp_cg_shim_warns_and_matches():
-    a = np.diag(np.linspace(1.0, 5.0, 16)).astype(np.float32)
-    b = np.ones(16, np.float32)
-    import repro.gp.cg as shim
-    from repro.gp.cg import cg_solve as shim_solve
-
-    shim._WARNED = False                  # the shim warns once per process
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        res = shim_solve(lambda v: jnp.asarray(a) @ v, jnp.asarray(b),
-                         tol=1e-7, max_iters=100)
-    assert any(issubclass(w.category, DeprecationWarning) for w in caught)
-    np.testing.assert_allclose(np.array(res.x), np.linalg.solve(a, b),
-                               rtol=1e-4, atol=1e-4)
-
-
-def test_gp_cg_shim_warns_exactly_once_and_reexports():
-    """The warn-once rule (hot loops through the shim must not drown real
-    warnings) and the re-exported strategy surface (ISSUE 6 additions)."""
-    a = np.diag(np.linspace(1.0, 5.0, 16)).astype(np.float32)
-    b = jnp.ones(16)
-    import repro.gp.cg as shim
-
-    shim._WARNED = False
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        for _ in range(3):
-            shim.cg_solve(lambda v: jnp.asarray(a) @ v, b,
-                          tol=1e-7, max_iters=100)
-        shim.cg_solve_fixed(lambda v: jnp.asarray(a) @ v, b, iters=4)
-    deps = [w for w in caught if issubclass(w.category, DeprecationWarning)]
-    assert len(deps) == 1, [str(w.message) for w in deps]
-    for name in ("SolveStrategy", "CGResult", "resolve_strategy",
-                 "select_rank", "PRECONDITIONERS", "MATVEC_DTYPES",
-                 "AUTO_RANKS", "DEFAULT_PRECOND_RANK"):
-        assert hasattr(shim, name), name
-    # The re-exported classes ARE the solvers ones (no parallel types).
-    assert shim.SolveStrategy is solvers.SolveStrategy
-
-
 def test_public_exports():
     import repro.gp as gp
 
     for name in ("SolveStrategy", "cg_solve_fixed", "slq_logdet", "solve",
-                 "CGResult", "cg_solve", "exact_lml",
-                 "init_inducing_pivoted"):
+                 "exact_lml", "init_inducing_pivoted"):
         assert hasattr(gp, name), name
+    # The Krylov stack lives in repro.solvers alone.
+    for name in ("cg", "CGResult", "cg_solve"):
+        assert not hasattr(gp, name), name
     for name in ("SolveStrategy", "CGResult", "cg_solve", "cg_solve_fixed",
                  "slq_logdet", "solve", "nystrom_precond", "pivot_rows",
                  "make_preconditioner", "jacobi_precond", "resolve_strategy",
